@@ -11,9 +11,9 @@ executors — also emitted as BENCH_backends.json for the perf trajectory),
 spgemm_sweep (sparse×sparse engine — emitted as BENCH_spgemm.json),
 serving_bench (GNN inference serving — emitted as BENCH_serving.json),
 cluster_bench (multi-lane scale-out serving — emitted as
-BENCH_cluster.json; always a subprocess, because it must set the 8-device
-host-platform flag before jax initializes), roofline (§Roofline from
-dry-run).
+BENCH_cluster.json; in-process on a TPU, a subprocess elsewhere, because
+off the chip it must set the 8-device host-platform flag before jax
+initializes), roofline (§Roofline from dry-run).
 
 The BENCH_*.json files together are the reproducible perf trajectory —
 ``--backends`` / ``--spgemm`` / ``--serving`` / ``--cluster`` rerun any
@@ -29,9 +29,11 @@ import sys
 import time
 import traceback
 
-from benchmarks import (backend_sweep, bloat_table, cpi_histograms,
-                        gnn_speedup, kernel_bench, mapping_heatmap,
-                        roofline, serving_bench, speedup_table, spgemm_sweep)
+from benchmarks import (backend_sweep, bloat_table, cluster_bench,
+                        cpi_histograms, gnn_speedup, kernel_bench,
+                        mapping_heatmap, roofline, serving_bench,
+                        speedup_table, spgemm_sweep)
+from repro.core import platform
 
 MODULES = [
     ("table1_bloat", bloat_table),
@@ -52,10 +54,17 @@ SERVING_JSON = serving_bench.DEFAULT_JSON
 CLUSTER_JSON = "BENCH_cluster.json"
 
 
-def _run_cluster_subprocess():
-    """cluster_bench needs ``--xla_force_host_platform_device_count=8`` set
-    BEFORE jax initializes — by the time run.py gets here jax is long live,
-    so the cluster slice always runs in a child process."""
+def _run_cluster():
+    """On a TPU the chip belongs to this process (a child could not open
+    it), so cluster_bench runs in-process over the real devices.  Off the
+    chip it needs ``--xla_force_host_platform_device_count=8`` set BEFORE
+    jax initializes — by the time run.py gets here jax is long live, so
+    the CPU slice runs in a child process."""
+    if platform.on_tpu():
+        rc = cluster_bench.main([])
+        if rc:
+            raise RuntimeError(f"cluster_bench returned {rc}")
+        return
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     proc = subprocess.run(
@@ -72,7 +81,7 @@ TRAJECTORY = [
      lambda: spgemm_sweep.write_json(SPGEMM_JSON, spgemm_sweep.collect())),
     ("serving", SERVING_JSON,
      lambda: serving_bench.write_json(SERVING_JSON, serving_bench.collect())),
-    ("cluster", CLUSTER_JSON, _run_cluster_subprocess),
+    ("cluster", CLUSTER_JSON, _run_cluster),
 ]
 
 
@@ -102,8 +111,8 @@ def main() -> None:
                     help="only the SpGEMM engine sweep (BENCH_spgemm.json)")
     ap.add_argument("--cluster", action="store_true",
                     help="only the scale-out serving benchmark "
-                         "(BENCH_cluster.json; subprocess on an emulated "
-                         "8-device mesh)")
+                         "(BENCH_cluster.json; off a TPU, a subprocess on "
+                         "an emulated 8-device mesh)")
     args = ap.parse_args()
 
     only = [n for n, flag in (("serving", args.serving),

@@ -13,7 +13,6 @@ import jax.numpy as jnp        # noqa: E402
 import numpy as np             # noqa: E402
 
 from repro.core import distributed, drhm   # noqa: E402
-from repro.core.compat import use_mesh             # noqa: E402
 
 
 def main():
@@ -24,7 +23,8 @@ def main():
     vals = rng.normal(size=e).astype(np.float32)
     x = rng.normal(size=(n, d)).astype(np.float32)
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     plan = distributed.plan_distributed_spmm(rows, cols, vals, n,
                                              n_shards=4, ring=True)
     print(f"DRHM plan: {plan.n_shards} shards × {plan.rows_per_shard} rows, "
@@ -34,7 +34,7 @@ def main():
 
     ag = distributed.make_allgather_spmm(mesh, plan)     # paper-faithful
     ring = distributed.make_ring_spmm(mesh, plan)        # overlap schedule
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         y1 = ag(xp, jnp.asarray(plan.rows_local),
                 jnp.asarray(plan.cols_perm), jnp.asarray(plan.vals))
         y2 = ring(xp, jnp.asarray(plan.ring_rows),

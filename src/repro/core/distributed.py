@@ -37,7 +37,6 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.core import drhm
-from repro.core.compat import pvary, shard_map
 from repro.sparse.graph import round_up
 
 Array = jax.Array
@@ -185,7 +184,7 @@ def make_allgather_spmm_dims(mesh, rows_per_shard: int, data_axis="data",
         # stage 2: NeuraMem — local accumulate into owned row block
         return jax.ops.segment_sum(pp, rows_l, num_segments=r_per)
 
-    return shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(data_axis, model_axis), P(data_axis), P(data_axis), P(data_axis)),
         out_specs=P(data_axis, model_axis),
@@ -221,7 +220,7 @@ def make_halo_gather(mesh, n_ghost_slot: int, data_axis="data"):
                          n_ghost_slot).astype(jnp.int32)
         return jnp.take(x_full, jnp.take(perm, ridx), axis=0)[None]
 
-    return shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(data_axis), P(), P(data_axis)),
         out_specs=P(data_axis),
@@ -243,7 +242,7 @@ def make_owner_accumulate(mesh, rows_per_shard: int, data_axis="data"):
     def local_fn(m_loc, rows_l):
         return jax.ops.segment_sum(m_loc, rows_l, num_segments=r_per)
 
-    return shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(data_axis), P(data_axis)),
         out_specs=P(data_axis),
@@ -292,11 +291,11 @@ def make_ring_spmm_dims(mesh, rows_per_shard: int, n_shards: int,
                      else (data_axis,))
         if model_axis:
             vary_axes = vary_axes + (model_axis,)
-        acc0 = pvary(acc0, vary_axes)
+        acc0 = jax.lax.pcast(acc0, vary_axes, to="varying")
         acc, _ = jax.lax.fori_loop(0, n_sh, hop, (acc0, x_loc))
         return acc
 
-    return shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(data_axis, model_axis), P(data_axis, None, None),
                   P(data_axis, None, None), P(data_axis, None, None)),

@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core import platform
+
 N_SLOTS = 2
 
 
@@ -54,27 +56,25 @@ def _kernel(ids_smem, table_hbm, out_ref, acc_ref, slot_ref, sems,
 
         @pl.when(m == 0)                      # fresh bag → reset accumulator
         def _():
-            pl.store(acc_ref, (pl.dslice(0, 1), slice(None)),
-                     jnp.zeros_like(slot_ref[s, :])[None])
+            acc_ref[pl.ds(0, 1), :] = jnp.zeros_like(slot_ref[s, :])[None]
 
-        cur = pl.load(acc_ref, (pl.dslice(0, 1), slice(None)))
-        pl.store(acc_ref, (pl.dslice(0, 1), slice(None)),
-                 cur + slot_ref[s, :][None])
+        acc_ref[pl.ds(0, 1), :] = (acc_ref[pl.ds(0, 1), :]
+                                   + slot_ref[s, :][None])
 
         @pl.when(m == bag - 1)                # bag complete → evict
         def _():
             d = slot_ref.shape[1]
-            val = pl.load(acc_ref, (pl.dslice(0, 1), slice(None)))
-            pl.store(out_ref, (pl.dslice(b_loc, 1),
-                               pl.dslice(f * d, d)), val)
+            out_ref[pl.ds(b_loc, 1), pl.ds(f * d, d)] = acc_ref[
+                pl.ds(0, 1), :]
         return 0
 
     jax.lax.fori_loop(0, n_look, body, 0)
 
 
+@platform.resolves_interpret
 @functools.partial(jax.jit, static_argnames=("batch_tile", "interpret"))
 def embedding_bag(ids: jax.Array, table: jax.Array, batch_tile: int = 8,
-                  interpret: bool = True) -> jax.Array:
+                  *, interpret: bool) -> jax.Array:
     """ids: (B, F, M) int32 (B % batch_tile == 0); table: (V, D).
     → (B, F·D) f32 (reshape to (B, F, D) outside)."""
     b, f, m = ids.shape
@@ -84,7 +84,7 @@ def embedding_bag(ids: jax.Array, table: jax.Array, batch_tile: int = 8,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n_tiles,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((batch_tile, f * d), lambda t, *_: (t, 0)),
         scratch_shapes=[
             pltpu.VMEM((1, d), jnp.float32),

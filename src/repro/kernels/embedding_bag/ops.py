@@ -11,8 +11,7 @@ def lookup(ids, table, batch_tile: int = 8, use_kernel: bool = True):
     """ids (B, F, M) → (B, F, D)."""
     b, f, _ = ids.shape
     if use_kernel:
-        out = embedding_bag(ids, table, batch_tile=batch_tile,
-                            interpret=jax.default_backend() != "tpu")
+        out = embedding_bag(ids, table, batch_tile=batch_tile)
     else:
         out = embedding_bag_ref(ids, table)
     return out.reshape(b, f, -1)

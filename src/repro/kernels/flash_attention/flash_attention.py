@@ -18,6 +18,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core import platform
+
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
             *, block_q: int, block_k: int, seq_len: int, scale: float):
@@ -34,14 +36,10 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
     def kv_block(ki, _):
         @pl.when(ki * block_k <= qi * block_q + block_q - 1)   # causal skip
         def _():
-            # leading dim via a 1-sized dslice: bare int indices are not
-            # accepted by pl.load on every pallas version
-            k = pl.load(k_ref, (pl.dslice(0, 1),
-                                pl.dslice(ki * block_k, block_k),
-                                slice(None)))[0].astype(jnp.float32)
-            v = pl.load(v_ref, (pl.dslice(0, 1),
-                                pl.dslice(ki * block_k, block_k),
-                                slice(None)))[0].astype(jnp.float32)
+            k = k_ref[0, pl.ds(ki * block_k, block_k), :].astype(
+                jnp.float32)
+            v = v_ref[0, pl.ds(ki * block_k, block_k), :].astype(
+                jnp.float32)
             s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32)
             k_pos = ki * block_k + jax.lax.broadcasted_iota(
@@ -63,11 +61,12 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
                 ).astype(o_ref.dtype)
 
 
+@platform.resolves_interpret
 @functools.partial(jax.jit,
                    static_argnames=("block_q", "block_k", "interpret"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     block_q: int = 256, block_k: int = 256,
-                    interpret: bool = True) -> jax.Array:
+                    *, interpret: bool) -> jax.Array:
     """q/k/v: (BH, S, d) — batch and heads pre-flattened, kv pre-repeated to
     full heads (GQA repeat happens in the caller).  Causal.  → (BH, S, d)."""
     bh, s, d = q.shape

@@ -20,8 +20,7 @@ def mha_causal(q, k, v, block_q: int = 256, block_k: int = 256,
     kf = k.transpose(0, 2, 1, 3).reshape(b * h, s, hd)
     vf = v.transpose(0, 2, 1, 3).reshape(b * h, s, hd)
     if use_kernel:
-        of = flash_attention(qf, kf, vf, block_q=block_q, block_k=block_k,
-                             interpret=jax.default_backend() != "tpu")
+        of = flash_attention(qf, kf, vf, block_q=block_q, block_k=block_k)
     else:
         of = causal_attention_ref(qf, kf, vf)
     return of.reshape(b, h, s, hd).transpose(0, 2, 1, 3)

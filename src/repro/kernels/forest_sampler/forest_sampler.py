@@ -31,6 +31,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core import platform
+
 _SM_GAMMA = 0x9E3779B97F4A7C15
 _SM_M1 = 0xBF58476D1CE4E5B9
 _SM_M2 = 0x94D049BB133111EB
@@ -123,16 +125,17 @@ def hash_draws_ref(z_hi, z_lo, deg):
     return mod64_pair(hi, lo, deg).astype(jnp.int32)
 
 
+@platform.resolves_interpret
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def hash_draws(z_hi: jax.Array, z_lo: jax.Array, deg: jax.Array,
-               interpret: bool = True) -> jax.Array:
+def hash_draws(z_hi: jax.Array, z_lo: jax.Array, deg: jax.Array, *,
+               interpret: bool) -> jax.Array:
     """``mix64(z) mod deg`` over a (T, L) counter grid → int32 draws.
 
     z_hi/z_lo: (T, L) uint32 halves of the combined counter; deg: (T, L)
     uint32 moduli (callers pass ``max(degree, 1)``).  The arrays are padded
     to the 32-bit VMEM tile (8, 128) and run as one whole-array grid step —
     the draw grid for a serving bucket is a few thousand lanes, far under
-    VMEM limits.
+    VMEM limits.  ``interpret`` follows ``core.platform.interpret_mode``.
     """
     t, l = z_hi.shape
     pt, plm = (-t) % 8, (-l) % 128

@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import jax
 
+from repro.core import platform
 from repro.kernels.forest_sampler.forest_sampler import (hash_draws,
                                                          hash_draws_ref,
                                                          split64)
@@ -17,14 +18,10 @@ from repro.kernels.forest_sampler.forest_sampler import (hash_draws,
 __all__ = ["counter_draws", "hash_draws", "hash_draws_ref", "split64"]
 
 
-def is_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def counter_draws(z_hi, z_lo, deg, use_kernel=None) -> jax.Array:
     """(T, L) int32 draws ``mix64(z) mod deg`` — kernel on TPU, jnp off."""
     if use_kernel is None:
-        use_kernel = is_tpu()
+        use_kernel = platform.on_tpu()
     if use_kernel:
-        return hash_draws(z_hi, z_lo, deg, interpret=not is_tpu())
+        return hash_draws(z_hi, z_lo, deg)
     return hash_draws_ref(z_hi, z_lo, deg)

@@ -37,7 +37,8 @@ exactly one ``(block_rows, width)`` tile and one chunk's operands per grid
 step — never whole arrays (the old layout re-copied the full vals array
 every step: O(n_blocks²·nnz_pad) operand traffic).
 
-Validated with interpret=True on CPU against ref.py; TPU is the target.
+Compiled for the TPU (the v5e compile rehearsals in
+``tests/test_tpu_compile.py``); interpreted on CPU against ref.py.
 """
 from __future__ import annotations
 
@@ -48,8 +49,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core import platform
+
 DEFAULT_GROUP = 8        # landing-buffer rows per DMA wave (MMH4 lane count)
-MAX_SINGLE_TILE_D = 512  # auto d_tile: keep one feature tile up to this width
+MAX_SINGLE_TILE_D = 1024  # auto d_tile: keep one feature tile up to this width
+LANES = 128              # TPU lane tile: compiled chunk widths / d_tiles
 
 
 def _fold(a_ref, first_smem, y_ref, land, k):
@@ -67,24 +71,21 @@ def _start_a_tile(a_hbm, a_ref, sem, k, block_rows):
         a_hbm.at[pl.dslice(k * block_rows, block_rows), :], a_ref, sem)
 
 
-def _kernel_dma(u_cols_smem, rem_smem, ob_smem, first_smem, a_hbm, x_hbm,
-                y_ref, a_ref, land_ref, sems, *, block_rows: int, group: int,
-                d_tile: int):
-    j = pl.program_id(0)
-    k = pl.program_id(1)
-    col0 = j * d_tile
-    a_cp = _start_a_tile(a_hbm, a_ref, sems.at[0], k, block_rows)
-    a_cp.start()
-    n_u = rem_smem[k]                        # rolling-eviction counter
-    n_waves = (n_u + group - 1) // group
+def _gather_waves(u_cols_smem, rem_smem, x_hbm, land_ref, sems, j, k, *,
+                  group: int, d_tiles: int):
+    """Multiply stage: row-gather chunk ``k``'s operands (feature tile
+    ``j``) into the landing buffer, every wave in flight before the first
+    wait — the pipeline is as deep as the landing buffer.  ``x_hbm`` is the
+    ``(N·d_tiles, d_tile)`` row-tile view of X: one operand row-tile is one
+    whole row of it, the only single-row DMA Mosaic accepts."""
+    n_waves = (rem_smem[k] + group - 1) // group   # rolling-eviction counter
     # zero the landing buffer: lanes no DMA wave touches must fold as exact
     # zeros (the coefficient tile is zero there, but 0·garbage could be NaN)
     land_ref[...] = jnp.zeros_like(land_ref)
 
     def wave_copies(w):
         return [pltpu.make_async_copy(
-                    x_hbm.at[u_cols_smem[k, w * group + t],
-                             pl.dslice(col0, d_tile)],
+                    x_hbm.at[u_cols_smem[k, w * group + t] * d_tiles + j],
                     land_ref.at[w * group + t], sems.at[1 + w * group + t])
                 for t in range(group)]
 
@@ -98,10 +99,19 @@ def _kernel_dma(u_cols_smem, rem_smem, ob_smem, first_smem, a_hbm, x_hbm,
             c.wait()
         return 0
 
-    # multiply stage: every wave's DMAs go in flight before the first wait —
-    # the pipeline is as deep as the landing buffer (n_waves × group lanes)
     jax.lax.fori_loop(0, n_waves, start_wave, 0)
     jax.lax.fori_loop(0, n_waves, wait_wave, 0)
+
+
+def _kernel_dma(u_cols_smem, rem_smem, ob_smem, first_smem, a_hbm, x_hbm,
+                y_ref, a_ref, land_ref, sems, *, block_rows: int, group: int,
+                d_tiles: int):
+    j = pl.program_id(0)
+    k = pl.program_id(1)
+    a_cp = _start_a_tile(a_hbm, a_ref, sems.at[0], k, block_rows)
+    a_cp.start()
+    _gather_waves(u_cols_smem, rem_smem, x_hbm, land_ref, sems, j, k,
+                  group=group, d_tiles=d_tiles)
     a_cp.wait()
     _fold(a_ref, first_smem, y_ref, land_ref[...], k)
 
@@ -122,26 +132,76 @@ def _kernel_stream(u_cols_smem, rem_smem, ob_smem, first_smem, a_hbm,
     _fold(a_ref, first_smem, y_ref, land_ref[...], k)
 
 
-def _auto_d_tile(d: int) -> int:
-    """Single tile up to MAX_SINGLE_TILE_D; beyond that, the smallest even
-    split (8-lane aligned) — a fixed 512 would pad D=576 to 1024.  TPU
-    callers wanting exact 128-lane tiles pass ``d_tile`` explicitly."""
-    if d <= MAX_SINGLE_TILE_D:
-        return d
+def _auto_d_tile(d: int, interpret: bool | None = None) -> int:
+    """The fewest feature tiles of at most MAX_SINGLE_TILE_D lanes.
+    Compiled, each is a multiple of 128 — Mosaic refuses a DMA column slice
+    of any other width, even one as wide as the array — so D=602 runs as one
+    640-lane tile.  The interpreter takes any width: one tile is exactly D,
+    a split rounds to 8 lanes."""
     n_tiles = -(-d // MAX_SINGLE_TILE_D)
+    interpreted = platform.interpret_mode(interpret)
+    if interpreted and n_tiles == 1:
+        return d
+    unit = 8 if interpreted else LANES
     per_tile = -(-d // n_tiles)
-    return -(-per_tile // 8) * 8
+    return -(-per_tile // unit) * unit
 
 
-@functools.partial(jax.jit, static_argnames=("block_rows", "n_blocks",
-                                             "group", "d_tile", "gather",
-                                             "interpret"))
+def _resolve(dtype, d: int, width: int, d_tile, gather: str, interpret):
+    """Concrete launch choices, made outside jit so a compiled kernel is
+    keyed by them and never by ``None``/``"auto"``.
+
+    ``"auto"`` gathers by DMA on a TPU for 32-bit operands.  A packed dtype
+    tiles as (16, 128) (bf16) or (32, 128) (int8), so one operand row is
+    not a whole tile row and Mosaic refuses its DMA: those stream.  The
+    compiled DMA path lands 128-lane feature tiles (a wider one-row DMA is
+    refused), and compiled chunks must be whole 128-lane tiles wide — the
+    packer emits them so on a TPU (``sparse.graph.chunk_width_multiple``).
+    """
+    interpret = platform.interpret_mode(interpret)
+    if not interpret and width % LANES:
+        raise ValueError(
+            f"chunk width {width} is not a multiple of {LANES}: the "
+            f"compiled kernel needs whole lane tiles (pack with "
+            f"width_multiple={LANES})")
+    if gather == "auto":
+        gather = ("dma" if platform.on_tpu()
+                  and jnp.dtype(dtype).itemsize == 4 else "stream")
+    if d_tile is None:
+        d_tile = (LANES if gather == "dma" and not interpret
+                  else _auto_d_tile(d, interpret))
+    return d_tile, gather, interpret
+
+
+def _pad_operands(u_cols, a, x, *, group: int, d_tile: int, gather: str):
+    """Pad the chunk width to whole DMA waves of ``group`` (``"dma"``) and
+    X to whole feature tiles.  Under ``gather="dma"`` X is returned as its
+    ``(N·d_tiles, d_tile)`` row-tile view."""
+    width = u_cols.shape[1]
+    lane_pad = (-width) % group if gather == "dma" else 0
+    if lane_pad:
+        u_cols = jnp.pad(u_cols, ((0, 0), (0, lane_pad)))
+        a = jnp.pad(a, ((0, 0), (0, lane_pad)))
+    n, d = x.shape
+    d_pad = (-d) % d_tile
+    if d_pad:
+        x = jnp.pad(x, ((0, 0), (0, d_pad)))
+    d_tiles = (d + d_pad) // d_tile
+    if gather == "dma":
+        x = x.reshape(n * d_tiles, d_tile)
+    else:
+        # multiply-stage gather hoisted to one vectorized XLA gather; each
+        # chunk's operand slab is contiguous → one strided DMA per step
+        x = jnp.take(x, u_cols.reshape(-1), axis=0)
+    return u_cols, a, x, d_tiles
+
+
 def spmm_dedup_chunks(u_cols: jax.Array, remaining: jax.Array,
                       out_block: jax.Array, first: jax.Array, a: jax.Array,
                       x: jax.Array, *, block_rows: int, n_blocks: int,
                       group: int = DEFAULT_GROUP, d_tile: int | None = None,
                       gather: str = "auto",
-                      interpret: bool = True) -> jax.Array:
+                      interpret: bool | None = None) -> jax.Array:
     """Chunked-dedup Gustavson SpMM:  y = A @ X on the packed layout.
 
     u_cols: (n_chunks, width) int32; remaining/out_block/first: (n_chunks,)
@@ -152,69 +212,57 @@ def spmm_dedup_chunks(u_cols: jax.Array, remaining: jax.Array,
     the TPU path, no operand materialization); ``"stream"`` pre-gathers the
     operands with one vectorized XLA gather and slab-DMAs each chunk (the
     fast path under interpret, where per-row copy emulation dominates);
-    ``"auto"`` picks by backend.
+    ``"auto"`` picks by backend and dtype (``_resolve``).  ``interpret``
+    defaults to the platform policy (``core.platform.interpret_mode``).
     """
-    n_chunks, width = u_cols.shape
-    d = x.shape[1]
-    if gather == "auto":
-        gather = "dma" if jax.default_backend() == "tpu" else "stream"
-    if d_tile is None:
-        d_tile = _auto_d_tile(d)
-    d_pad = (-d) % d_tile
-    if d_pad:
-        x = jnp.pad(x, ((0, 0), (0, d_pad)))
-    d_tiles = (d + d_pad) // d_tile
-    if gather == "dma":
-        # wave padding: DMA waves copy whole lanes-of-`group`
-        lane_pad = (-width) % group
-        if lane_pad:
-            u_cols = jnp.pad(u_cols, ((0, 0), (0, lane_pad)))
-            a = jnp.pad(a, ((0, 0), (0, lane_pad)))
-            width += lane_pad
+    d_tile, gather, interpret = _resolve(x.dtype, x.shape[1], u_cols.shape[1],
+                                         d_tile, gather, interpret)
+    return _spmm_dedup_chunks(u_cols, remaining, out_block, first, a, x,
+                              block_rows=block_rows, n_blocks=n_blocks,
+                              group=group, d_tile=d_tile, gather=gather,
+                              interpret=interpret)
 
+
+@functools.partial(jax.jit, static_argnames=("block_rows", "n_blocks",
+                                             "group", "d_tile", "gather",
+                                             "interpret"))
+def _spmm_dedup_chunks(u_cols, remaining, out_block, first, a, x, *,
+                       block_rows: int, n_blocks: int, group: int,
+                       d_tile: int, gather: str, interpret: bool):
+    d = x.shape[1]
+    u_cols, a, operand, d_tiles = _pad_operands(
+        u_cols, a, x, group=group, d_tile=d_tile, gather=gather)
+    n_chunks, width = u_cols.shape
     out_shape = jax.ShapeDtypeStruct((n_blocks * block_rows,
                                       d_tiles * d_tile), x.dtype)
     # grid: feature tiles outer, chunks inner — chunks of one output block
     # stay consecutive, so the revisited output tile is still resident
     out_spec = pl.BlockSpec((block_rows, d_tile),
                             lambda j, k, uc, re, ob, fi: (ob[k], j))
-    any_spec = pl.BlockSpec(memory_space=pltpu.ANY)
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
     if gather == "dma":
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,    # u_cols, remaining, out_block, first
-            grid=(d_tiles, n_chunks),
-            in_specs=[any_spec, any_spec],           # a, x (HBM)
-            out_specs=out_spec,
-            scratch_shapes=[
-                pltpu.VMEM((block_rows, width), a.dtype),   # coeff tile
-                pltpu.VMEM((width, d_tile), x.dtype),       # landing buffer
-                pltpu.SemaphoreType.DMA((1 + width,)),
-            ],
-        )
         kernel = functools.partial(_kernel_dma, block_rows=block_rows,
-                                   group=group, d_tile=d_tile)
-        operand = x
+                                   group=group, d_tiles=d_tiles)
+        n_sems = 1 + width
     else:
-        # multiply-stage gather hoisted to one vectorized XLA gather; each
-        # chunk's operand slab is contiguous → one strided DMA per step
-        operand = jnp.take(x, u_cols.reshape(-1), axis=0)
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
-            grid=(d_tiles, n_chunks),
-            in_specs=[any_spec, any_spec],           # a, operand slab (HBM)
-            out_specs=out_spec,
-            scratch_shapes=[
-                pltpu.VMEM((block_rows, width), a.dtype),
-                pltpu.VMEM((width, d_tile), x.dtype),
-                pltpu.SemaphoreType.DMA((2,)),
-            ],
-        )
         kernel = functools.partial(_kernel_stream, block_rows=block_rows,
                                    width=width, d_tile=d_tile)
+        n_sems = 2
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,        # u_cols, remaining, out_block, first
+        grid=(d_tiles, n_chunks),
+        in_specs=[any_spec, any_spec],   # a, X row tiles / operand slab
+        out_specs=out_spec,
+        scratch_shapes=[
+            pltpu.VMEM((block_rows, width), a.dtype),   # coeff tile
+            pltpu.VMEM((width, d_tile), x.dtype),       # landing buffer
+            pltpu.SemaphoreType.DMA((n_sems,)),
+        ],
+    )
     y = pl.pallas_call(kernel, grid_spec=grid_spec, out_shape=out_shape,
                        interpret=interpret)(
         u_cols, remaining, out_block, first, a, operand)
-    return y[:, :d] if d_pad else y
+    return y[:, :d] if d_tiles * d_tile != d else y
 
 
 # ---------------------------------------------------------------------------
@@ -242,35 +290,13 @@ def _fold_q8(a_ref, first_smem, ascale_smem, xscale_smem, y_ref, land, j, k):
 
 def _kernel_dma_q8(u_cols_smem, rem_smem, ob_smem, first_smem, ascale_smem,
                    xscale_smem, a_hbm, x_hbm, y_ref, a_ref, land_ref, sems, *,
-                   block_rows: int, group: int, d_tile: int):
+                   block_rows: int, group: int, d_tiles: int):
     j = pl.program_id(0)
     k = pl.program_id(1)
-    col0 = j * d_tile
     a_cp = _start_a_tile(a_hbm, a_ref, sems.at[0], k, block_rows)
     a_cp.start()
-    n_u = rem_smem[k]
-    n_waves = (n_u + group - 1) // group
-    land_ref[...] = jnp.zeros_like(land_ref)
-
-    def wave_copies(w):
-        return [pltpu.make_async_copy(
-                    x_hbm.at[u_cols_smem[k, w * group + t],
-                             pl.dslice(col0, d_tile)],
-                    land_ref.at[w * group + t], sems.at[1 + w * group + t])
-                for t in range(group)]
-
-    def start_wave(w, _):
-        for c in wave_copies(w):
-            c.start()
-        return 0
-
-    def wait_wave(w, _):
-        for c in wave_copies(w):
-            c.wait()
-        return 0
-
-    jax.lax.fori_loop(0, n_waves, start_wave, 0)
-    jax.lax.fori_loop(0, n_waves, wait_wave, 0)
+    _gather_waves(u_cols_smem, rem_smem, x_hbm, land_ref, sems, j, k,
+                  group=group, d_tiles=d_tiles)
     a_cp.wait()
     _fold_q8(a_ref, first_smem, ascale_smem, xscale_smem, y_ref,
              land_ref[...], j, k)
@@ -293,9 +319,6 @@ def _kernel_stream_q8(u_cols_smem, rem_smem, ob_smem, first_smem, ascale_smem,
              land_ref[...], j, k)
 
 
-@functools.partial(jax.jit, static_argnames=("block_rows", "n_blocks",
-                                             "group", "d_tile", "gather",
-                                             "interpret"))
 def spmm_dedup_chunks_q8(u_cols: jax.Array, remaining: jax.Array,
                          out_block: jax.Array, first: jax.Array,
                          a_q8: jax.Array, a_scale: jax.Array,
@@ -303,82 +326,78 @@ def spmm_dedup_chunks_q8(u_cols: jax.Array, remaining: jax.Array,
                          block_rows: int, n_blocks: int,
                          group: int = DEFAULT_GROUP,
                          d_tile: int | None = None, gather: str = "auto",
-                         interpret: bool = True) -> jax.Array:
+                         interpret: bool | None = None) -> jax.Array:
     """int8-operand Gustavson SpMM:  y ≈ A @ X, f32 output.
 
     a_q8: (n_chunks·block_rows, width) int8 with a_scale (n_chunks,) f32;
     x_q8: (N, D) int8 with x_scale (ceil(D/d_tile),) f32 — ``d_tile`` MUST
     match the tile width the scales were computed with
-    (``quantize_feature_tiles(x, d_tile)``), else the rescale is wrong.
+    (``quantize_feature_tiles(x, d_tile)``; ``None`` means
+    ``_auto_d_tile(D, interpret)``), else the rescale is wrong.
     Output is always f32 (cross-chunk accumulation of rescaled folds).
     """
-    n_chunks, width = u_cols.shape
     d = x_q8.shape[1]
-    if gather == "auto":
-        gather = "dma" if jax.default_backend() == "tpu" else "stream"
     if d_tile is None:
-        d_tile = _auto_d_tile(d)
-    d_pad = (-d) % d_tile
-    if d_pad:
-        x_q8 = jnp.pad(x_q8, ((0, 0), (0, d_pad)))
-    d_tiles = (d + d_pad) // d_tile
+        d_tile = _auto_d_tile(d, interpret)
+    d_tile, gather, interpret = _resolve(x_q8.dtype, d, u_cols.shape[1],
+                                         d_tile, gather, interpret)
+    d_tiles = -(-d // d_tile)
     if x_scale.shape[0] != d_tiles:
         raise ValueError(
             f"x_scale has {x_scale.shape[0]} tiles for d_tiles={d_tiles}; "
             f"quantize with the same d_tile the kernel runs with")
-    if gather == "dma":
-        lane_pad = (-width) % group
-        if lane_pad:
-            u_cols = jnp.pad(u_cols, ((0, 0), (0, lane_pad)))
-            a_q8 = jnp.pad(a_q8, ((0, 0), (0, lane_pad)))
-            width += lane_pad
+    return _spmm_dedup_chunks_q8(
+        u_cols, remaining, out_block, first, a_q8, a_scale, x_q8, x_scale,
+        block_rows=block_rows, n_blocks=n_blocks, group=group, d_tile=d_tile,
+        gather=gather, interpret=interpret)
 
+
+@functools.partial(jax.jit, static_argnames=("block_rows", "n_blocks",
+                                             "group", "d_tile", "gather",
+                                             "interpret"))
+def _spmm_dedup_chunks_q8(u_cols, remaining, out_block, first, a_q8, a_scale,
+                          x_q8, x_scale, *, block_rows: int, n_blocks: int,
+                          group: int, d_tile: int, gather: str,
+                          interpret: bool):
+    d = x_q8.shape[1]
+    u_cols, a_q8, operand, d_tiles = _pad_operands(
+        u_cols, a_q8, x_q8, group=group, d_tile=d_tile, gather=gather)
+    n_chunks, width = u_cols.shape
     out_shape = jax.ShapeDtypeStruct((n_blocks * block_rows,
                                       d_tiles * d_tile), jnp.float32)
     out_spec = pl.BlockSpec((block_rows, d_tile),
                             lambda j, k, uc, re, ob, fi, sa, sx: (ob[k], j))
-    any_spec = pl.BlockSpec(memory_space=pltpu.ANY)
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
     if gather == "dma":
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            # u_cols, remaining, out_block, first, a_scale, x_scale
-            num_scalar_prefetch=6,
-            grid=(d_tiles, n_chunks),
-            in_specs=[any_spec, any_spec],           # a_q8, x_q8 (HBM)
-            out_specs=out_spec,
-            scratch_shapes=[
-                pltpu.VMEM((block_rows, width), jnp.int8),   # coeff tile
-                pltpu.VMEM((width, d_tile), jnp.int8),       # landing buffer
-                pltpu.SemaphoreType.DMA((1 + width,)),
-            ],
-        )
         kernel = functools.partial(_kernel_dma_q8, block_rows=block_rows,
-                                   group=group, d_tile=d_tile)
-        operand = x_q8
+                                   group=group, d_tiles=d_tiles)
+        n_sems = 1 + width
     else:
-        operand = jnp.take(x_q8, u_cols.reshape(-1), axis=0)
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=6,
-            grid=(d_tiles, n_chunks),
-            in_specs=[any_spec, any_spec],
-            out_specs=out_spec,
-            scratch_shapes=[
-                pltpu.VMEM((block_rows, width), jnp.int8),
-                pltpu.VMEM((width, d_tile), jnp.int8),
-                pltpu.SemaphoreType.DMA((2,)),
-            ],
-        )
         kernel = functools.partial(_kernel_stream_q8, block_rows=block_rows,
                                    width=width, d_tile=d_tile)
+        n_sems = 2
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        # u_cols, remaining, out_block, first, a_scale, x_scale
+        num_scalar_prefetch=6,
+        grid=(d_tiles, n_chunks),
+        in_specs=[any_spec, any_spec],           # a_q8, X / operand slab
+        out_specs=out_spec,
+        scratch_shapes=[
+            pltpu.VMEM((block_rows, width), jnp.int8),   # coeff tile
+            pltpu.VMEM((width, d_tile), jnp.int8),       # landing buffer
+            pltpu.SemaphoreType.DMA((n_sems,)),
+        ],
+    )
     y = pl.pallas_call(kernel, grid_spec=grid_spec, out_shape=out_shape,
                        interpret=interpret)(
         u_cols, remaining, out_block, first,
         a_scale.astype(jnp.float32), x_scale.astype(jnp.float32),
         a_q8, operand)
-    return y[:, :d] if d_pad else y
+    return y[:, :d] if d_tiles * d_tile != d else y
 
 
 def spmm_blocked_ell(cols, row_local, vals, remaining, x,
-                     block_rows: int = 8, interpret: bool = True,
+                     block_rows: int = 8, interpret: bool | None = None,
                      group: int = DEFAULT_GROUP, d_tile: int | None = None,
                      gather: str = "auto") -> jax.Array:
     """Per-lane blocked-ELL compatibility entry (host-side inputs only).

@@ -18,12 +18,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import platform
 from repro.kernels.gustavson_spmm.gustavson_spmm import (
     _auto_d_tile, spmm_dedup_chunks, spmm_dedup_chunks_q8)
-
-
-def is_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def _float0_zeros(a: jax.Array):
@@ -89,10 +86,8 @@ def spmm_dedup_grad(u_cols, remaining, out_block, first, a,
                     interpret=None):
     """Differentiable chunked-dedup SpMM (grads flow to ``a``, ``a_t`` —
     i.e. to edge values through the coefficient scatters — and ``x``)."""
-    if interpret is None:
-        interpret = not is_tpu()
     statics = (block_rows, n_blocks, n_t_blocks, group, d_tile, gather,
-               bool(interpret))
+               platform.interpret_mode(interpret))
     return _spmm_dedup_ad(statics, u_cols, remaining, out_block, first, a,
                           t_u_cols, t_remaining, t_out_block, t_first, a_t,
                           x)
@@ -116,7 +111,8 @@ def _spmm_dedup_q8_ad(statics, u_cols, remaining, out_block, first, a,
                       t_first, a_t, x):
     block_rows, n_blocks, _, group, d_tile, gather, interpret = statics
     from repro.sparse.quantize import quantize_feature_tiles
-    dt = d_tile if d_tile is not None else _auto_d_tile(x.shape[1])
+    dt = d_tile if d_tile is not None else _auto_d_tile(x.shape[1],
+                                                        interpret)
     x_q8, x_scale = quantize_feature_tiles(x, dt)
     y = spmm_dedup_chunks_q8(u_cols, remaining, out_block, first, a_q8,
                              a_scale, x_q8, x_scale, block_rows=block_rows,
@@ -162,13 +158,11 @@ def spmm_dedup_grad_q8(u_cols, remaining, out_block, first, a,
     ``a_q8``/``a_scale`` may be baked plan-time tiles; when ``None`` the f32
     tiles ``a`` are quantized per chunk in-trace (for traced edge values).
     """
-    if interpret is None:
-        interpret = not is_tpu()
     if a_q8 is None:
         from repro.sparse.quantize import quantize_chunk_tiles
         a_q8, a_scale = quantize_chunk_tiles(a, u_cols.shape[0])
     statics = (block_rows, n_blocks, n_t_blocks, group, d_tile, gather,
-               bool(interpret))
+               platform.interpret_mode(interpret))
     return _spmm_dedup_q8_ad(statics, u_cols, remaining, out_block, first,
                              a, a_q8, a_scale, t_u_cols, t_remaining,
                              t_out_block, t_first, a_t, x)
@@ -188,7 +182,7 @@ def spmm(rows: np.ndarray, cols: np.ndarray, vals: np.ndarray, x,
     n_blocks = ch.n_blocks
     if use_kernel:
         y = spmm_dedup_chunks(*args, x, block_rows=block_rows,
-                              n_blocks=n_blocks, interpret=not is_tpu())
+                              n_blocks=n_blocks)
     else:
         y = spmm_dedup_chunks_ref(args[0], args[2], args[4], x,
                                   block_rows, n_blocks)

@@ -15,8 +15,7 @@ def edge_scores(src, dst, x, y, edge_block: int = 256, use_kernel: bool = True):
         src = jnp.pad(src, (0, pad))
         dst = jnp.pad(dst, (0, pad))
     if use_kernel:
-        out = sddmm(src, dst, x, y, edge_block=edge_block,
-                    interpret=jax.default_backend() != "tpu")
+        out = sddmm(src, dst, x, y, edge_block=edge_block)
     else:
         out = sddmm_ref(src, dst, x, y)
     return out[:e]

@@ -16,6 +16,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core import platform
+
 N_SLOTS = 2
 
 
@@ -44,15 +46,16 @@ def _kernel(src_smem, dst_smem, x_hbm, y_hbm, out_ref,
             start(i + 1)
 
         dot = jnp.sum(xs_ref[s, :] * ys_ref[s, :])
-        pl.store(out_ref, (pl.dslice(i, 1),), dot[None])
+        out_ref[pl.ds(i, 1)] = dot[None]
         return 0
 
     jax.lax.fori_loop(0, edge_block, body, 0)
 
 
+@platform.resolves_interpret
 @functools.partial(jax.jit, static_argnames=("edge_block", "interpret"))
 def sddmm(src: jax.Array, dst: jax.Array, x: jax.Array, y: jax.Array,
-          edge_block: int = 256, interpret: bool = True) -> jax.Array:
+          edge_block: int = 256, *, interpret: bool) -> jax.Array:
     """src/dst: (E,) int32 (E % edge_block == 0); x/y: (N, D).  → (E,) f32."""
     e = src.shape[0]
     assert e % edge_block == 0
@@ -60,8 +63,8 @@ def sddmm(src: jax.Array, dst: jax.Array, x: jax.Array, y: jax.Array,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(n_blocks,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY),
-                  pl.BlockSpec(memory_space=pltpu.ANY)],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((edge_block,), lambda b, *_: (b,)),
         scratch_shapes=[
             pltpu.VMEM((N_SLOTS, x.shape[1]), jnp.float32),

@@ -15,10 +15,6 @@ from repro.kernels.spgemm_pad.spgemm_pad import (spgemm_hashpad,
                                                  spgemm_hashpad_q8)
 
 
-def is_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def hashpad_accumulate(out_block, first, evict, a, slab, *, block_rows: int,
                        n_blocks: int, pad_width: int,
                        h_tile: int | None = None, interpret=None,
@@ -27,12 +23,10 @@ def hashpad_accumulate(out_block, first, evict, a, slab, *, block_rows: int,
     if not use_kernel:
         return spgemm_hashpad_ref(out_block, a, slab, block_rows, n_blocks,
                                   pad_width)
-    if interpret is None:
-        interpret = not is_tpu()
     return spgemm_hashpad(out_block, first, evict, a, slab,
                           block_rows=block_rows, n_blocks=n_blocks,
                           pad_width=pad_width, h_tile=h_tile,
-                          interpret=bool(interpret))
+                          interpret=interpret)
 
 
 def hashpad_accumulate_q8(out_block, first, evict, a_q8, a_scale, slab_q8,
@@ -40,9 +34,8 @@ def hashpad_accumulate_q8(out_block, first, evict, a_q8, a_scale, slab_q8,
                           pad_width: int, h_tile: int | None = None,
                           interpret=None) -> jax.Array:
     """int8-operand hash-pad accumulation (pallas_q8 SpGEMM executor)."""
-    if interpret is None:
-        interpret = not is_tpu()
     return spgemm_hashpad_q8(out_block, first, evict, a_q8, a_scale,
                              slab_q8, slab_scale, block_rows=block_rows,
                              n_blocks=n_blocks, pad_width=pad_width,
-                             h_tile=h_tile, interpret=bool(interpret))
+                             h_tile=h_tile,
+                             interpret=interpret)

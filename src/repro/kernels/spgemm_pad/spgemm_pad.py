@@ -32,7 +32,8 @@ Grid = ``(h_tiles, n_chunks)``: the pad axis is tiled like the SpMM
 kernel's feature axis; the chunk axis is innermost so the pad stays
 resident across a block's chunks.  out_block/first/evict are
 scalar-prefetched to SMEM; the output BlockSpec index map reads
-``out_block[k]``.  Validated with interpret=True on CPU against ref.py.
+``out_block[k]``.  Compiled on a TPU, interpreted elsewhere
+(``core.platform``); validated on CPU against ref.py.
 """
 from __future__ import annotations
 
@@ -42,6 +43,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.core import platform
 
 MAX_SINGLE_TILE_H = 512  # auto h_tile: one pad tile up to this lane count
 
@@ -77,13 +80,14 @@ def _auto_h_tile(h: int) -> int:
     return h if h <= MAX_SINGLE_TILE_H else MAX_SINGLE_TILE_H
 
 
+@platform.resolves_interpret
 @functools.partial(jax.jit, static_argnames=("block_rows", "n_blocks",
                                              "pad_width", "h_tile",
                                              "interpret"))
 def spgemm_hashpad(out_block: jax.Array, first: jax.Array, evict: jax.Array,
                    a: jax.Array, slab: jax.Array, *, block_rows: int,
                    n_blocks: int, pad_width: int, h_tile: int | None = None,
-                   interpret: bool = True) -> jax.Array:
+                   interpret: bool) -> jax.Array:
     """C_pad = fold(A_tiles @ slab) over chunks → (n_blocks·block_rows, H).
 
     out_block/first/evict: (n_chunks,) int32; a: (n_chunks·block_rows,
@@ -104,7 +108,7 @@ def spgemm_hashpad(out_block: jax.Array, first: jax.Array, evict: jax.Array,
     # consecutive, so the pad scratch survives until its eviction step
     out_spec = pl.BlockSpec((block_rows, h_tile),
                             lambda j, k, ob, fi, ev: (ob[k], j))
-    any_spec = pl.BlockSpec(memory_space=pltpu.ANY)
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,          # out_block, first, evict
         grid=(h_tiles, n_chunks),
@@ -120,8 +124,8 @@ def spgemm_hashpad(out_block: jax.Array, first: jax.Array, evict: jax.Array,
     kernel = functools.partial(_kernel, block_rows=block_rows, width=width,
                                h_tile=h_tile)
     return pl.pallas_call(kernel, grid_spec=grid_spec, out_shape=out_shape,
-                          interpret=interpret)(out_block, first, evict, a,
-                                               slab)
+                          interpret=interpret)(
+        out_block, first, evict, a, slab)
 
 
 # ---------------------------------------------------------------------------
@@ -162,6 +166,7 @@ def _kernel_q8(ob_smem, first_smem, evict_smem, ascale_smem, bscale_smem,
         y_ref[...] = pad_ref[...]
 
 
+@platform.resolves_interpret
 @functools.partial(jax.jit, static_argnames=("block_rows", "n_blocks",
                                              "pad_width", "h_tile",
                                              "interpret"))
@@ -170,7 +175,7 @@ def spgemm_hashpad_q8(out_block: jax.Array, first: jax.Array,
                       slab_q8: jax.Array, slab_scale: jax.Array, *,
                       block_rows: int, n_blocks: int, pad_width: int,
                       h_tile: int | None = None,
-                      interpret: bool = True) -> jax.Array:
+                      interpret: bool) -> jax.Array:
     """int8-operand hash-pad SpGEMM: C_pad ≈ fold(A_tiles @ slab), f32 out.
 
     a_q8: (n_chunks·block_rows, width) int8 with a_scale (n_chunks,) f32;
@@ -188,7 +193,7 @@ def spgemm_hashpad_q8(out_block: jax.Array, first: jax.Array,
                                      jnp.float32)
     out_spec = pl.BlockSpec((block_rows, h_tile),
                             lambda j, k, ob, fi, ev, sa, sb: (ob[k], j))
-    any_spec = pl.BlockSpec(memory_space=pltpu.ANY)
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,   # out_block, first, evict, a_scale, b_scale
         grid=(h_tiles, n_chunks),

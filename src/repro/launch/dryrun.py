@@ -12,7 +12,6 @@ import jax               # noqa: E402
 
 from repro.configs import registry, shapes as S               # noqa: E402
 from repro.launch import analysis, flops as flops_mod, hlo_costs, sharding, steps  # noqa: E402
-from repro.core.compat import use_mesh                        # noqa: E402
 from repro.launch.mesh import make_production_mesh            # noqa: E402
 from repro.optim import adamw                                 # noqa: E402
 
@@ -72,7 +71,7 @@ def lower_cell(arch_id: str, shape_name: str, multi_pod: bool,
     in_sh = sharding.to_named(in_pspec, mesh)
 
     t0 = time.time()
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         if steps.needs_optimizer(arch_id, shape):
             opt = jax.eval_shape(adamw.init_state, params)
             opt_pspec = sharding.opt_state_pspecs(p_pspec)

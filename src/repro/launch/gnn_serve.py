@@ -20,11 +20,14 @@ trees) and checks parity.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
+from typing import Optional
 
 import jax
 import numpy as np
 
+from repro.core import platform
 from repro.data import synthetic as syn
 from repro.serve import (ClusterServer, FeatureStore, GNNServer,
                          offline_inference)
@@ -32,14 +35,17 @@ from repro.sparse.graph import coo_to_csr
 from repro.sparse.plan import ALL_BACKENDS
 
 
-def build_world(arch: str, n_nodes: int, n_edges: int, d_in: int,
-                seed: int = 0):
-    """(cfg, params, indptr, indices, store) on a synthetic resident graph."""
+def build_world(arch: str, n_nodes: int, n_edges: int,
+                d_in: Optional[int] = None, seed: int = 0):
+    """(cfg, params, indptr, indices, store) on a synthetic resident graph.
+
+    Conv archs take their config's own widths (``d_in``, ``n_classes``);
+    ``d_in`` overrides the input width only.
+    """
     s, r = syn.powerlaw_graph(n_nodes, n_edges, seed=seed)
     indptr, indices, _ = coo_to_csr(s, r, n_nodes)
     rng = np.random.default_rng(seed + 1)
     key = jax.random.key(seed)
-    x = rng.normal(size=(n_nodes, d_in)).astype(np.float32)
     if arch in ("schnet", "dimenet"):
         mod = __import__(f"repro.models.gnn.{arch}", fromlist=[arch])
         # explicit small configs keep the CPU driver snappy
@@ -58,8 +64,11 @@ def build_world(arch: str, n_nodes: int, n_edges: int, d_in: int,
             "sage": ("sage", "SAGEConfig"), "gin": ("gin", "GINConfig")}
     name, cfg_name = mods[arch]
     mod = __import__(f"repro.models.gnn.{name}", fromlist=[name])
-    cfg = getattr(mod, cfg_name)(d_in=d_in, n_classes=8)
+    cfg = getattr(mod, cfg_name)()
+    if d_in is not None:
+        cfg = dataclasses.replace(cfg, d_in=d_in)
     params = mod.init_params(key, cfg)
+    x = rng.normal(size=(n_nodes, cfg.d_in)).astype(np.float32)
     return cfg, params, indptr, indices, FeatureStore.build(n_nodes, x=x)
 
 
@@ -192,6 +201,8 @@ def run_cluster(args, fanouts, cfg, params, indptr, indices, store) -> int:
                   f"reroutes={st['reroutes']} retries={st['retries']} "
                   f"timeouts={st['timeouts']} shed={st['shed']} "
                   f"failed={st['failed']}")
+            if chaos is None and (st["failed"] or st["timeouts"]):
+                return 1
         if args.slo:
             for cls, s in st.get("classes", {}).items():
                 print(f"[gnn-serve] slo {cls:<12} n={s['n']:<6} "
@@ -244,7 +255,9 @@ def main():
     ap.add_argument("--requests", type=int, default=100)
     ap.add_argument("--nodes", type=int, default=2048)
     ap.add_argument("--edges", type=int, default=8192)
-    ap.add_argument("--d-in", type=int, default=32)
+    ap.add_argument("--d-in", type=int, default=None,
+                    help="input feature width (default: the arch "
+                         "config's own)")
     ap.add_argument("--fanouts", default="5,3")
     ap.add_argument("--max-batch", type=int, default=16)
     ap.add_argument("--max-wait-ms", type=float, default=5.0)
@@ -323,6 +336,7 @@ def main():
                     help="bounded-staleness window: the mutation stream "
                          "auto-flushes every N buffered edges")
     args = ap.parse_args()
+    platform.enable_compile_cache()
 
     fanouts = tuple(int(f) for f in args.fanouts.split(","))
     cfg, params, indptr, indices, store = build_world(
@@ -347,13 +361,17 @@ def main():
         server.drain()
         dt = time.perf_counter() - t0
         st = server.stats()
+        failed = sum(1 for r in reqs if not r.done or r.error is not None)
         print(f"[gnn-serve] {args.arch}/{args.backend}: "
               f"{args.requests} requests in {dt:.2f}s "
               f"({args.requests / dt:.1f} req/s)  "
               f"p50={st['p50_ms']:.1f}ms p95={st['p95_ms']:.1f}ms "
               f"p99={st['p99_ms']:.1f}ms  "
               f"batches={st['n_batches']} buckets={st['bucket_counts']} "
-              f"recompiles(post-warmup)={server.steps.builds - warm_builds}")
+              f"recompiles(post-warmup)={server.steps.builds - warm_builds} "
+              f"failed={failed}")
+        if failed:
+            return 1
         if not args.skip_offline:
             t0 = time.perf_counter()
             ref = np.concatenate(

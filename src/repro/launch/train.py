@@ -24,6 +24,7 @@ import numpy as np
 
 from repro.configs import registry
 from repro.configs import shapes as S
+from repro.core import platform
 from repro.data import synthetic as syn
 from repro.launch import steps as steps_mod
 from repro.models.lm.transformer import LMConfig
@@ -108,6 +109,7 @@ def main():
                     help="aggregate over the SpGEMM-precomputed Â² two-hop "
                          "graph (sum-aggregation GNNs, e.g. gcn-cora)")
     args = ap.parse_args()
+    platform.enable_compile_cache()
 
     if args.preset == "lm100m":
         cfg = LM100M

@@ -533,8 +533,7 @@ def moe_mlp_sharded(p, cfg: LMConfig, x: Array, capacity: int,
         w_spec = (P(tp, None, None),) * 3
     else:
         w_spec = (P(None, None, tp), P(None, None, tp), P(None, tp, None))
-    from repro.core.compat import shard_map
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn,
         in_specs=(P(), *w_spec, P(token_axes, None)),
         out_specs=P(token_axes, None),

@@ -348,7 +348,9 @@ class ClusterServer:
                     "run under XLA_FLAGS=--xla_force_host_platform_"
                     f"device_count={self.n_lanes} (or placement='stacked' "
                     "replicated, which is device-count-agnostic)")
-            self.mesh = jax.make_mesh((self.n_lanes,), ("lane",))
+            self.mesh = jax.make_mesh(
+                (self.n_lanes,), ("lane",),
+                axis_types=(jax.sharding.AxisType.Auto,))
 
         # telemetry plane — the source of truth stats() derives from, and
         # the signal every control arm (supervision, shedding, scaling)

@@ -20,6 +20,7 @@ serving benchmark assert to be zero after bucket warm-up.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable, Dict, Optional
 
 import jax
@@ -27,6 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.serve.buckets import BucketStructure, build_bucket_structure
+from repro.sparse.graph import chunk_width_multiple
 from repro.sparse.plan import make_plan, plan_with_values
 
 Array = jax.Array
@@ -78,6 +80,14 @@ class FeatureStore:
             jnp.int32)
 
 
+# a pytree, so steps take the resident tables as jit ARGUMENTS: a closed-over
+# array is embedded in the program as a constant (the Reddit-width table is
+# ~0.56 GB), once per bucket step
+jax.tree_util.register_dataclass(FeatureStore,
+                                 data_fields=["x", "species", "pos"],
+                                 meta_fields=["n_nodes"])
+
+
 # ---------------------------------------------------------------------------
 # Step/plan cache — bounded LRU with the recompile counter tests assert on
 # ---------------------------------------------------------------------------
@@ -121,7 +131,7 @@ class StepCache:
 # ---------------------------------------------------------------------------
 
 def _build_bucket_plan(key: tuple):
-    n_seeds, fanouts, with_loops, backend, need_ell = key
+    n_seeds, fanouts, with_loops, backend, need_ell, width_multiple = key
     struct = build_bucket_structure(n_seeds, fanouts, with_loops=with_loops)
     backends = ["dense", "chunked"]
     if backend in ("pallas", "pallas_q8") and need_ell:
@@ -129,7 +139,7 @@ def _build_bucket_plan(key: tuple):
     if backend == "distributed":
         backends.append("distributed")
     return make_plan(struct.senders, struct.receivers, struct.n_nodes,
-                     backends=tuple(backends))
+                     backends=tuple(backends), width_multiple=width_multiple)
 
 
 _BUCKET_PLANS = StepCache(_build_bucket_plan, maxsize=32)
@@ -139,7 +149,8 @@ def bucket_plan(struct: BucketStructure, backend: str, need_ell: bool):
     """Host aggregation plan for a bucket's static edge structure, all edges
     valid (per-request validity flows in via ``plan_with_values``)."""
     return _BUCKET_PLANS.get((struct.n_seeds, struct.fanouts,
-                              struct.with_loops, backend, bool(need_ell)))
+                              struct.with_loops, backend, bool(need_ell),
+                              chunk_width_multiple()))
 
 
 def bucket_plan_cache_info() -> dict:
@@ -170,8 +181,9 @@ def build_infer_step(arch_id: str, cfg, store: FeatureStore,
                      struct: BucketStructure, backend: str = "dense",
                      jit: bool = True) -> Callable:
     """``step(params, node_ids, hop_valid) -> (n_seeds, d_out)`` for one
-    bucket, jitted.  ``node_ids``/``hop_valid`` are the only traced inputs;
-    everything else (structure, plans, store) is closed over."""
+    bucket, jitted, with ``store`` bound as its first jit argument.  The
+    bucket's structure and plan are closed over.  ``jit=False`` returns the
+    unbound traced body ``step(store, params, node_ids, hop_valid)``."""
     arch = _arch_key(arch_id)
     n = struct.n_nodes
     k = struct.n_seeds
@@ -199,7 +211,7 @@ def build_infer_step(arch_id: str, cfg, store: FeatureStore,
     if arch == "gcn":
         from repro.models.gnn import gcn as m
 
-        def step(params, node_ids, hop_valid):
+        def step(store, params, node_ids, hop_valid):
             x = jnp.take(store.x, store.row_index(node_ids), axis=0)
             ev = edge_validity(node_ids, hop_valid)
             # symmetric normalization on the sampled subgraph, traced:
@@ -217,7 +229,7 @@ def build_infer_step(arch_id: str, cfg, store: FeatureStore,
         import importlib
         m = importlib.import_module(f"repro.models.gnn.{arch}")
 
-        def step(params, node_ids, hop_valid):
+        def step(store, params, node_ids, hop_valid):
             x = jnp.take(store.x, store.row_index(node_ids), axis=0)
             pl = plan_with_values(plan0,
                                   edge_valid=edge_validity(node_ids,
@@ -228,7 +240,7 @@ def build_infer_step(arch_id: str, cfg, store: FeatureStore,
         from repro.models.gnn import schnet as m
         graph_ids = jnp.arange(n, dtype=jnp.int32)
 
-        def step(params, node_ids, hop_valid):
+        def step(store, params, node_ids, hop_valid):
             idx = store.row_index(node_ids)
             species = jnp.take(store.species, idx)
             pos = jnp.take(store.pos, idx, axis=0)
@@ -245,7 +257,7 @@ def build_infer_step(arch_id: str, cfg, store: FeatureStore,
         t_in = jnp.asarray(struct.t_in)
         t_out = jnp.asarray(struct.t_out)
 
-        def step(params, node_ids, hop_valid):
+        def step(store, params, node_ids, hop_valid):
             idx = store.row_index(node_ids)
             species = jnp.take(store.species, idx)
             pos = jnp.take(store.pos, idx, axis=0)
@@ -257,7 +269,7 @@ def build_infer_step(arch_id: str, cfg, store: FeatureStore,
                           plan=pl)
             return e[:k, None]
 
-    return jax.jit(step) if jit else step
+    return functools.partial(jax.jit(step), store) if jit else step
 
 
 # ---------------------------------------------------------------------------
@@ -340,15 +352,15 @@ def build_lane_infer_step(arch_id: str, cfg, struct: BucketStructure,
         raise ValueError("placement='mesh' needs a 1-D ('lane',) mesh")
     from jax.sharding import PartitionSpec as P
 
-    from repro.core.compat import shard_map
-
     def lane_fn(params, x, node_ids, hop_valid):
         return body(params, x[0], node_ids[0], hop_valid[0])[None]
 
-    return jax.jit(shard_map(
+    # check_vma=False: a lane runs no collective, and the Pallas kernels'
+    # out_shapes carry no varying-axes annotation for the checker to match
+    return jax.jit(jax.shard_map(
         lane_fn, mesh=mesh,
         in_specs=(P(), P("lane"), P("lane"), P("lane")),
-        out_specs=P("lane")))
+        out_specs=P("lane"), check_vma=False))
 
 
 def build_fetch_step(store: FeatureStore) -> Callable:
@@ -357,8 +369,8 @@ def build_fetch_step(store: FeatureStore) -> Callable:
     padding lanes).  The sharded-residency counterpart is
     ``core.distributed.make_halo_gather`` — same rows, different transport,
     bitwise-equal output."""
-    def fetch(node_ids):
+    def fetch(store, node_ids):
         return jnp.take(store.x, store.row_index(node_ids), axis=0)
-    return jax.jit(fetch)
+    return functools.partial(jax.jit(fetch), store)
 
 

@@ -57,6 +57,9 @@ class DeviceSamplerPlane:
     Holds the CSR arrays on device and the per-hop constant counter terms
     ``mix64(key) ⊕ hop·C₂ ⊕ lane·C₃`` (uint32 halves, precomputed host-side
     once — they depend only on ``(key, fanouts)``, never on requests).
+
+    A pytree (arrays are leaves): the fused step takes the plane as a jit
+    argument, so the resident CSR is never embedded as a program constant.
     """
 
     def __init__(self, indptr: np.ndarray, indices: np.ndarray,
@@ -141,6 +144,22 @@ class DeviceSamplerPlane:
         return node_ids, hop_valid
 
 
+def _plane_flatten(p: DeviceSamplerPlane):
+    return ((p.indptr, p.indices, p._hop_consts),
+            (p.fanouts, p.n_edges, p.use_kernel))
+
+
+def _plane_unflatten(aux, leaves):
+    p = object.__new__(DeviceSamplerPlane)
+    p.indptr, p.indices, p._hop_consts = leaves
+    p.fanouts, p.n_edges, p.use_kernel = aux
+    return p
+
+
+jax.tree_util.register_pytree_node(DeviceSamplerPlane, _plane_flatten,
+                                   _plane_unflatten)
+
+
 def sample_forest_device(indptr: np.ndarray, indices: np.ndarray,
                          seeds: np.ndarray, fanouts: Sequence[int],
                          key: int = 0, tree_keys: np.ndarray = None,
@@ -160,8 +179,8 @@ def sample_forest_device(indptr: np.ndarray, indices: np.ndarray,
     plane = DeviceSamplerPlane(indptr, indices, fanouts, key=key,
                                use_kernel=use_kernel)
     tk_hi, tk_lo = tree_key_mix(tree_keys)
-    levels, valid_hops = jax.jit(plane.sample_levels)(
-        jnp.asarray(seeds.astype(np.int32)), jnp.asarray(tk_hi),
+    levels, valid_hops = jax.jit(DeviceSamplerPlane.sample_levels)(
+        plane, jnp.asarray(seeds.astype(np.int32)), jnp.asarray(tk_hi),
         jnp.asarray(tk_lo), jnp.ones((n_trees,), bool))
     nodes_all = np.concatenate([np.asarray(lv, np.int64) for lv in levels],
                                axis=1)

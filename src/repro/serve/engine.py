@@ -417,18 +417,20 @@ class GNNServer:
                                     struct, backend=self.backend)
         # fused dispatch: sampling + feature gather + GNN forward in ONE
         # jitted program per bucket — the step's traced inputs shrink from
-        # the stacked node tables to seeds + per-tree counter keys
+        # the stacked node tables to seeds + per-tree counter keys (the
+        # resident CSR and features are bound arguments, not constants)
+        import functools
+
         import jax
         body = build_infer_step(self.arch_id, self.cfg, self.store, struct,
                                 backend=self.backend, jit=False)
-        plane = self._plane
 
-        def fused(params, seeds, tk_hi, tk_lo, live):
+        def fused(plane, store, params, seeds, tk_hi, tk_lo, live):
             node_ids, hop_valid = plane.sample_bucket(seeds, tk_hi, tk_lo,
                                                       live)
-            return body(params, node_ids, hop_valid)
+            return body(store, params, node_ids, hop_valid)
 
-        return jax.jit(fused)
+        return functools.partial(jax.jit(fused), self._plane, self.store)
 
     def _struct(self, bucket: int):
         if bucket not in self._structs:

@@ -291,8 +291,7 @@ def _pallas_q8_aggregate(plan, vals, x):
             plan.ell_u_cols, plan.ell_remaining, plan.ell_out_block,
             plan.ell_first, a_q8, a_scale, x.q8, x.scale,
             block_rows=plan.block_rows, n_blocks=plan.n_blocks,
-            group=plan.ell_group, d_tile=plan.ell_d_tile,
-            interpret=not gops.is_tpu())
+            group=plan.ell_group, d_tile=plan.ell_d_tile)
         return y[: plan.n_rows]
     # X quantizes per feature tile inside the op (the scales must be computed
     # with the kernel's own d_tile); output returns in x.dtype

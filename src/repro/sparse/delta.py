@@ -41,8 +41,8 @@ from typing import List, Optional, Sequence, Tuple
 import jax.numpy as jnp
 import numpy as np
 
-from repro.sparse.graph import (DedupChunks, Graph, make_graph, pad_to,
-                                round_up)
+from repro.sparse.graph import (DedupChunks, Graph, chunk_width_multiple,
+                                make_graph, pad_to, round_up)
 from repro.sparse.stats import record_count, record_value
 
 DELTA_BACKENDS = ("dense", "chunked", "pallas", "pallas_q8")
@@ -184,7 +184,7 @@ class _LayoutState:
         return nch, int(nch.sum())
 
     def assemble(self, vals: np.ndarray,
-                 width_multiple: int = 16) -> DedupChunks:
+                 width_multiple: Optional[int] = None) -> DedupChunks:
         """Materialize the flat DedupChunks arrays — all vectorized; no
         python loop over blocks.  Bitwise-matches ``pack_dedup_chunks``
         over the canonical edge arrays (per-cell accumulation order is
@@ -193,7 +193,7 @@ class _LayoutState:
         counts_u = np.diff(self.u_ptr)
         nch, n_chunks = self.chunk_layout()
         width = int(round_up(max(1, min(int(counts_u.max(initial=0)), cap)),
-                             width_multiple))
+                             chunk_width_multiple(width_multiple)))
         chunk_start = np.zeros(self.n_blocks + 1, np.int64)
         np.cumsum(nch, out=chunk_start[1:])
         blk_of_u = np.repeat(np.arange(self.n_blocks), counts_u)
@@ -248,12 +248,12 @@ class DeltaGraphState:
     def __init__(self, senders: np.ndarray, receivers: np.ndarray,
                  n_nodes: int, weights: Optional[np.ndarray] = None, *,
                  block_rows: int = 8, width_cap: int = 128,
-                 width_multiple: int = 16):
+                 width_multiple: Optional[int] = None):
         self.n_nodes = int(n_nodes)
         self.n_rows = self.n_nodes + 1            # ghost-row convention
         self.block_rows = int(block_rows)
         self.width_cap = int(width_cap)
-        self.width_multiple = int(width_multiple)
+        self.width_multiple = chunk_width_multiple(width_multiple)
         self._s = np.asarray(senders, np.int64).copy()
         self._r = np.asarray(receivers, np.int64).copy()
         if np.any((self._s < 0) | (self._s >= self.n_nodes) |

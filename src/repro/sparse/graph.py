@@ -70,8 +70,7 @@ def coo_to_csr(senders: np.ndarray, receivers: np.ndarray, n_nodes: int):
     s_sorted = senders[order]
     r_sorted = receivers[order]
     indptr = np.zeros(n_nodes + 1, dtype=np.int64)
-    np.add.at(indptr, r_sorted + 1, 1)
-    indptr = np.cumsum(indptr)
+    np.cumsum(np.bincount(r_sorted, minlength=n_nodes), out=indptr[1:])
     return indptr, s_sorted.astype(np.int32), order
 
 
@@ -270,17 +269,31 @@ def chunk_block_edges(b: int, idx: np.ndarray, rows: np.ndarray,
     return chunks
 
 
+def chunk_width_multiple(width_multiple: Optional[int] = None) -> int:
+    """What chunk widths round to: whole 128-lane tiles where the Gustavson
+    kernel compiles for the chip (Mosaic slices its coefficient tile and
+    landing buffer only at that width), 16 under the interpreter, where a
+    narrow chunk costs less.  An explicit multiple wins."""
+    if width_multiple is not None:
+        return int(width_multiple)
+    from repro.core import platform   # repro.core imports this module
+    from repro.kernels.gustavson_spmm.gustavson_spmm import LANES
+    return 16 if platform.interpret_mode() else LANES
+
+
 def assemble_dedup_chunks(per_block: list, vals: np.ndarray, n_edges: int,
                           n_rows: int, n_cols: int, block_rows: int,
-                          width_multiple: int = 16) -> DedupChunks:
+                          width_multiple: Optional[int] = None
+                          ) -> DedupChunks:
     """Assemble per-block chunk tuples (from :func:`chunk_block_edges`)
     into the flat DedupChunks arrays.  ``width`` adapts to the graph: the
-    max distinct-operand count over chunks, rounded to ``width_multiple``
-    — balanced graphs get narrow tiles, hub-heavy ones get more chunks.
+    max distinct-operand count over chunks, rounded to
+    ``chunk_width_multiple(width_multiple)`` — balanced graphs get narrow
+    tiles, hub-heavy ones get more chunks.
     """
     width = int(round_up(max(1, max((c[1].size for chunks in per_block
                                      for c in chunks), default=1)),
-                         width_multiple))
+                         chunk_width_multiple(width_multiple)))
     n_chunks = sum(len(c) for c in per_block)
     u_cols = np.zeros((n_chunks, width), np.int32)
     a = np.zeros((n_chunks * block_rows, width), np.float32)
@@ -308,7 +321,7 @@ def assemble_dedup_chunks(per_block: list, vals: np.ndarray, n_edges: int,
 def pack_dedup_chunks(rows: np.ndarray, cols: np.ndarray, vals: np.ndarray,
                       n_rows: int, n_cols: int, block_rows: int = 8,
                       width_cap: int = 128,
-                      width_multiple: int = 16) -> DedupChunks:
+                      width_multiple: Optional[int] = None) -> DedupChunks:
     """Pack COO into DedupChunks (host-side, once per graph).
 
     ``width`` adapts to the graph: the max distinct-operand count over

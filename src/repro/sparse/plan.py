@@ -181,7 +181,7 @@ def make_plan(senders: np.ndarray, receivers: np.ndarray, n_rows: int,
               edge_valid: Optional[np.ndarray] = None, *,
               backends: Sequence[str] = ("dense", "chunked"),
               chunk: int = 8192, block_rows: int = 8, width_cap: int = 128,
-              width_multiple: int = 16, group: int = 8,
+              width_multiple: Optional[int] = None, group: int = 8,
               d_tile: Optional[int] = None,
               mesh=None, gamma: int = 0x9E3779B1,
               edge_pad_multiple: int = 8) -> AggregationPlan:
@@ -255,7 +255,8 @@ def make_plan(senders: np.ndarray, receivers: np.ndarray, n_rows: int,
     if "distributed" in backends:
         from repro.core.distributed import plan_distributed_spmm
         if mesh is None:
-            mesh = jax.make_mesh((jax.device_count(),), ("data",))
+            mesh = jax.make_mesh((jax.device_count(),), ("data",),
+                                 axis_types=(jax.sharding.AxisType.Auto,))
         n_shards = int(mesh.shape["data"])
         dp = plan_distributed_spmm(r[vidx], s[vidx], base[vidx], int(n_rows),
                                    n_shards=n_shards, gamma=gamma,

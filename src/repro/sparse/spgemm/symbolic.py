@@ -417,7 +417,8 @@ def make_spgemm_plan(a_rows: np.ndarray, a_cols: np.ndarray, n_rows: int,
                      b_vals: Optional[np.ndarray] = None,
                      executors: Sequence[str] = ALL_SPGEMM_EXECUTORS,
                      block_rows: int = 8, width_cap: int = 128,
-                     width_multiple: int = 16, chunk: int = 8192,
+                     width_multiple: Optional[int] = None,
+                     chunk: int = 8192,
                      pad_slack: float = 2.0, max_reseeds: int = 8,
                      max_pad_width: int = 1 << 16,
                      seed: int = 0) -> SpgemmPlan:

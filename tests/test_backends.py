@@ -6,6 +6,7 @@ passes over random graphs, including empty-row and all-padding edge cases;
 ``distributed`` parity runs in a subprocess over 8 emulated devices.
 """
 import functools
+import os
 import subprocess
 import sys
 from pathlib import Path
@@ -527,8 +528,11 @@ def test_distributed_backend_subprocess():
     repo = Path(__file__).resolve().parent.parent
     proc = subprocess.run(
         [sys.executable, "-c", DIST_SCRIPT], capture_output=True, text=True,
+        # JAX_PLATFORMS must survive into the child (see
+        # test_cluster_serving.test_sharded_cluster_subprocess)
         env={"PYTHONPATH": str(repo / "src"), "PATH": "/usr/bin:/bin",
-             "HOME": "/root"},
+             "HOME": os.environ.get("HOME", str(repo)),
+             "JAX_PLATFORMS": os.environ.get("JAX_PLATFORMS", "cpu")},
         timeout=600)
     assert proc.returncode == 0, proc.stderr[-3000:]
     assert "BACKEND_DIST_OK" in proc.stdout

@@ -49,7 +49,8 @@ def test_elastic_restore_new_sharding(tmp_path):
     """Restore maps onto a different device layout (topology-free manifest)."""
     t = _tree()
     store.save(tmp_path, 7, t)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     from jax.sharding import NamedSharding, PartitionSpec as P
     sh = jax.tree.map(lambda _: NamedSharding(mesh, P()), t)
     like = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), t)

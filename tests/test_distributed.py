@@ -15,7 +15,6 @@ import pytest
 BODY = r"""
 import numpy as np, jax, jax.numpy as jnp
 from repro.core import distributed
-from repro.core.compat import shard_map, use_mesh
 
 rng = np.random.default_rng(1)
 n, e, d = 96, 700, 32
@@ -25,20 +24,21 @@ x = rng.normal(size=(n, d)).astype(np.float32)
 dense = np.zeros((n, n), np.float32); np.add.at(dense, (rows, cols), vals)
 ref = dense @ x
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 plan = distributed.plan_distributed_spmm(rows, cols, vals, n, n_shards=4,
                                          ring=True)
 xp = distributed.permute_features(x, plan)
 
 f = distributed.make_allgather_spmm(mesh, plan)
-with use_mesh(mesh):
+with jax.set_mesh(mesh):
     y = f(jnp.asarray(xp), jnp.asarray(plan.rows_local),
           jnp.asarray(plan.cols_perm), jnp.asarray(plan.vals))
 err = abs(distributed.unpermute_features(np.asarray(y), plan, n) - ref).max()
 assert err < 1e-4, f"allgather spmm err {err}"
 
 g = distributed.make_ring_spmm(mesh, plan)
-with use_mesh(mesh):
+with jax.set_mesh(mesh):
     y2 = g(jnp.asarray(xp), jnp.asarray(plan.ring_rows),
            jnp.asarray(plan.ring_cols), jnp.asarray(plan.ring_vals))
 err2 = abs(distributed.unpermute_features(np.asarray(y2), plan, n) - ref).max()
@@ -52,7 +52,7 @@ def loss_ring(xp_):
     return jnp.sum(g(xp_, jnp.asarray(plan.ring_rows),
                      jnp.asarray(plan.ring_cols),
                      jnp.asarray(plan.ring_vals))**2)
-with use_mesh(mesh):
+with jax.set_mesh(mesh):
     g1 = jax.grad(loss_ag)(jnp.asarray(xp))
     g2 = jax.grad(loss_ring)(jnp.asarray(xp))
 gerr = float(jnp.abs(g1 - g2).max())
@@ -69,9 +69,9 @@ def ps(z):
 def cps(z):
     return compressed_psum(z, "data")
 z = rng.normal(size=(8, 64)).astype(np.float32)
-sm_ps = shard_map(ps, mesh=mesh, in_specs=P("data"), out_specs=P())
-sm_cps = shard_map(cps, mesh=mesh, in_specs=P("data"), out_specs=P())
-with use_mesh(mesh):
+sm_ps = jax.shard_map(ps, mesh=mesh, in_specs=P("data"), out_specs=P())
+sm_cps = jax.shard_map(cps, mesh=mesh, in_specs=P("data"), out_specs=P())
+with jax.set_mesh(mesh):
     a = sm_ps(jnp.asarray(z))
     b = sm_cps(jnp.asarray(z))
 cerr = float(jnp.abs(a - b).max() / (jnp.abs(a).max() + 1e-9))

@@ -231,7 +231,8 @@ def test_engine_deadline_expiry_yields_error_terminal():
         assert rec["spans"][-1]["error"] == "DeadlineExceeded"
 
 
-@settings(max_examples=5)
+# no deadline: each example starts and drains a server's threads
+@settings(max_examples=5, deadline=None)
 @given(st.integers(min_value=1, max_value=24))
 def test_engine_property_every_accepted_request_traced(n_requests):
     srv = _server(_world(), tracing=True)

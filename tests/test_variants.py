@@ -6,6 +6,7 @@
    different device count.
 Run in a subprocess so the XLA device-count flag cannot leak.
 """
+import os
 import subprocess
 import sys
 from pathlib import Path
@@ -17,7 +18,6 @@ import dataclasses
 import numpy as np, jax, jax.numpy as jnp
 from repro.configs import registry
 from repro.core import distributed
-from repro.core.compat import use_mesh
 from repro.launch import variants
 from repro.models.gnn import gcn
 from repro.optim import adamw
@@ -41,7 +41,8 @@ ref_loss = gcn.loss_fn(params, cfg, jnp.asarray(x), jnp.asarray(s2),
                        jnp.asarray(mask))
 
 # ---- DRHM-sharded step on a (4, 2) mesh ----
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+AUTO = (jax.sharding.AxisType.Auto,) * 2
+mesh = jax.make_mesh((4, 2), ("data", "model"), axis_types=AUTO)
 # aggregation direction: rows=receivers, cols=senders
 plan = distributed.plan_distributed_spmm(r2, s2, w, n, n_shards=4)
 xp = distributed.permute_features(x, plan)
@@ -56,7 +57,7 @@ batch = {"x_perm": jnp.asarray(xp), "labels_perm": jnp.asarray(yp),
 step = variants.build_gcn_drhm_step(cfg, mesh, plan.n_pad, ring=False,
                                     opt_cfg=adamw.AdamWConfig(lr=1e-2))
 opt = adamw.init_state(params)
-with use_mesh(mesh):
+with jax.set_mesh(mesh):
     new_p, new_o, metrics = jax.jit(step)(params, opt, batch)
 err = abs(float(metrics["loss"]) - float(ref_loss))
 assert err < 1e-4, f"DRHM step loss mismatch: {err}"
@@ -68,7 +69,7 @@ from repro.checkpoint import store
 import tempfile
 tmp = tempfile.mkdtemp()
 store.save(tmp, 1, (new_p, new_o))
-mesh1 = jax.make_mesh((1, 1), ("data", "model"))
+mesh1 = jax.make_mesh((1, 1), ("data", "model"), axis_types=AUTO)
 from jax.sharding import NamedSharding, PartitionSpec as P
 like = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
                     (new_p, new_o))
@@ -84,8 +85,11 @@ def test_variants_subprocess():
     repo = Path(__file__).resolve().parent.parent
     proc = subprocess.run(
         [sys.executable, "-c", SCRIPT], capture_output=True, text=True,
+        # JAX_PLATFORMS must survive into the child: without it jax probes
+        # the TPU library and fails on host bounds
         env={"PYTHONPATH": str(repo / "src"), "PATH": "/usr/bin:/bin",
-             "HOME": "/root"},
+             "HOME": os.environ.get("HOME", str(repo)),
+             "JAX_PLATFORMS": os.environ.get("JAX_PLATFORMS", "cpu")},
         timeout=600)
     assert proc.returncode == 0, proc.stderr[-3000:]
     assert "VARIANT_LOSS_OK" in proc.stdout

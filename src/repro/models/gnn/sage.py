@@ -63,11 +63,14 @@ def forward(params, cfg: SAGEConfig, x: Array, senders: Array = None,
     h = x
     for i in range(cfg.n_layers):
         p = params[f"layer{i}"]
-        nbr = sb.aggregate(pl, None, h, backend=backend) * inv_deg
-        h = (h @ p["w_self"].astype(h.dtype)
-             + nbr @ p["w_nbr"].astype(h.dtype) + p["b"].astype(h.dtype))
-        if i < cfg.n_layers - 1:
-            h = jax.nn.relu(h)
+        # stable names for a profile: the neighbour mean, then the dense part
+        with jax.named_scope(f"layer{i}.aggregate"):
+            nbr = sb.aggregate(pl, None, h, backend=backend) * inv_deg
+        with jax.named_scope(f"layer{i}.dense"):
+            h = (h @ p["w_self"].astype(h.dtype)
+                 + nbr @ p["w_nbr"].astype(h.dtype) + p["b"].astype(h.dtype))
+            if i < cfg.n_layers - 1:
+                h = jax.nn.relu(h)
     return h
 
 

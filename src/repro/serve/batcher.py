@@ -6,6 +6,10 @@ Seed-node requests coalesce into minibatches under two triggers:
 * **deadline** — the oldest pending request has waited ``max_wait`` seconds
   (bounded tail latency: a lone request never waits for a full batch).
 
+Each take stamps ``t_ripe``, the instant the batch's trigger fired, so a
+tracer can split a request's queue wait into the wait for its trigger and
+the wait for the engine after it (``engine_lag``).
+
 Packing is skip-ahead FIFO (``scheduler.pack_fifo``): a request that does
 not fit the remaining seed budget stays at the front of the line while
 later, smaller requests may still ride along — no head-of-line blocking.
@@ -133,6 +137,7 @@ class DynamicBatcher:
         self.n_submitted = 0
         self.n_batches = 0
         self.n_expired = 0
+        self.t_ripe = 0.0                 # when the last batch taken ripened
 
     def __len__(self) -> int:
         with self._lock:
@@ -180,7 +185,22 @@ class DynamicBatcher:
             return True                                   # size trigger
         return now - self._pending[0].t_ready >= self.max_wait  # deadline
 
-    def _take(self) -> List[ServeRequest]:
+    def _ripe_at(self) -> float:
+        """When the pending queue became ripe: the oldest request's
+        ``t_ready + max_wait``, or the moment the pending seeds reached
+        ``max_seeds`` if that came first (lock held, queue non-empty)."""
+        t = self._pending[0].t_ready + self.max_wait
+        if self._pending_seeds >= self.max_seeds:
+            seeds = 0
+            for r in self._pending:
+                seeds += r.n_seeds
+                if seeds >= self.max_seeds:
+                    return min(t, r.t_ready)
+        return t
+
+    def _take(self, now: float) -> List[ServeRequest]:
+        # a flush takes batches that never ripened: they ripen as taken
+        self.t_ripe = min(self._ripe_at(), now)
         taken, self._pending, used = pack_fifo(
             self._pending, self.max_seeds, size_of=lambda r: r.n_seeds)
         self._pending_seeds -= used
@@ -193,8 +213,9 @@ class DynamicBatcher:
     def poll(self) -> Optional[List[ServeRequest]]:
         """Non-blocking: a batch if a trigger has fired, else ``None``."""
         with self._lock:
-            if self._ripe(self.clock()):
-                return self._take()
+            now = self.clock()
+            if self._ripe(now):
+                return self._take(now)
             return None
 
     def take(self, timeout: Optional[float] = None
@@ -206,7 +227,7 @@ class DynamicBatcher:
             while True:
                 now = self.clock()
                 if self._ripe(now):
-                    return self._take()
+                    return self._take(now)
                 # sleep until the oldest request's deadline or the caller's
                 waits = []
                 if self._pending:
@@ -229,8 +250,13 @@ class DynamicBatcher:
         out = []
         with self._lock:
             while self._pending:
-                out.append(self._take())
+                out.append(self._take(self.clock()))
         return out
+
+    def oldest_ready(self) -> Optional[float]:
+        """``t_ready`` of the oldest pending request; None if none."""
+        with self._lock:
+            return self._pending[0].t_ready if self._pending else None
 
     def info(self) -> dict:
         """Queue counters as one observable (engine/cluster ``stats()``)."""

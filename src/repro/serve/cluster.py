@@ -74,8 +74,7 @@ from repro.serve.buckets import (all_buckets, bucket_for,
                                  build_bucket_structure, stack_trees)
 from repro.serve.compute import (CONV_ARCHS, FeatureStore, StepCache,
                                  _arch_key, build_fetch_step,
-                                 build_infer_step, build_lane_infer_step,
-                                 dispatch_annotation)
+                                 build_infer_step, build_lane_infer_step)
 from repro.serve.engine import SamplerPool, _needs_loops
 from repro.serve.errors import (DeadlineExceeded, DrainTimeout, LaneFailure,
                                 Overloaded, RetriesExhausted, SamplerError,
@@ -300,7 +299,6 @@ class ClusterServer:
                  scale_up_depth: float = 8.0, scale_down_depth: float = 0.25,
                  scale_sustain_ticks: int = 4,
                  tracing: bool = False, trace_capacity: int = 4096,
-                 profile_annotations: bool = False,
                  clock=time.monotonic):
         if mode not in MODES:
             raise ValueError(f"unknown mode {mode!r}; have {MODES}")
@@ -373,7 +371,6 @@ class ClusterServer:
         # the flushed span), so the per-lane hot-path spans share one cached
         # dict per lane instead of allocating per request
         self._lane_attrs = [{"lane": ln} for ln in range(self.n_lanes)]
-        self.profile_annotations = bool(profile_annotations)
 
         # routing plane
         self.router = DRHMRouter(self.n_lanes, n_bins=router_bins, seed=seed,
@@ -1130,14 +1127,8 @@ class ClusterServer:
                                                           self.fanouts)
         t_pack1 = self.clock() if tr is not None else 0.0
         params, pver = self._live_params    # ONE atomic read per round
-        if self.profile_annotations:
-            with dispatch_annotation(
-                    f"neurachip:dispatch_round:b{bucket}"):
-                x = self._gather(node_ids)
-                out = step(params, x, node_ids, hop_valid)
-        else:
-            x = self._gather(node_ids)
-            out = step(params, x, node_ids, hop_valid)  # async dispatch
+        x = self._gather(node_ids)
+        out = step(params, x, node_ids, hop_valid)  # async dispatch
         slots = {lane: self.pools.acquire(lane, ready[lane][0].rid)
                  for lane in ready}
         now = self.clock()

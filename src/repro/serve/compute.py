@@ -159,20 +159,6 @@ def bucket_plan_cache_info() -> dict:
     return _BUCKET_PLANS.info()
 
 
-def dispatch_annotation(label: str):
-    """Opt-in ``jax.profiler`` trace annotation around a lane dispatch — a
-    context manager that names the dispatch window in a jax profiler trace
-    (``jax.profiler.trace(...)`` around the traffic), and degrades to a
-    no-op when the profiler surface is unavailable.  Never on by default:
-    the annotation itself costs a TraceMe record per round."""
-    try:
-        from jax.profiler import TraceAnnotation
-        return TraceAnnotation(label)
-    except Exception:  # pragma: no cover - depends on jax build surface
-        import contextlib
-        return contextlib.nullcontext()
-
-
 # ---------------------------------------------------------------------------
 # Inference steps
 # ---------------------------------------------------------------------------
@@ -212,7 +198,8 @@ def build_infer_step(arch_id: str, cfg, store: FeatureStore,
         from repro.models.gnn import gcn as m
 
         def step(store, params, node_ids, hop_valid):
-            x = jnp.take(store.x, store.row_index(node_ids), axis=0)
+            with jax.named_scope("serve.gather"):
+                x = jnp.take(store.x, store.row_index(node_ids), axis=0)
             ev = edge_validity(node_ids, hop_valid)
             # symmetric normalization on the sampled subgraph, traced:
             # in-degree over valid edges (self loops included)
@@ -230,7 +217,8 @@ def build_infer_step(arch_id: str, cfg, store: FeatureStore,
         m = importlib.import_module(f"repro.models.gnn.{arch}")
 
         def step(store, params, node_ids, hop_valid):
-            x = jnp.take(store.x, store.row_index(node_ids), axis=0)
+            with jax.named_scope("serve.gather"):
+                x = jnp.take(store.x, store.row_index(node_ids), axis=0)
             pl = plan_with_values(plan0,
                                   edge_valid=edge_validity(node_ids,
                                                            hop_valid))

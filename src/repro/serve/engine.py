@@ -252,6 +252,8 @@ class GNNServer:
         # nothing (the property tests pin the zero-span claim)
         self.tracer = (Tracer(capacity=trace_capacity, clock=clock)
                        if tracing else None)
+        if self.tracer is not None:
+            self.tracer.watch_gc()
 
         self.batcher = DynamicBatcher(self.max_batch_seeds,
                                       max_wait_ms / 1e3, clock=clock)
@@ -426,8 +428,9 @@ class GNNServer:
                                 backend=self.backend, jit=False)
 
         def fused(plane, store, params, seeds, tk_hi, tk_lo, live):
-            node_ids, hop_valid = plane.sample_bucket(seeds, tk_hi, tk_lo,
-                                                      live)
+            with jax.named_scope("serve.sample"):
+                node_ids, hop_valid = plane.sample_bucket(seeds, tk_hi,
+                                                          tk_lo, live)
             return body(store, params, node_ids, hop_valid)
 
         return functools.partial(jax.jit(fused), self._plane, self.store)
@@ -474,28 +477,40 @@ class GNNServer:
             packed = self._device_batch(batch, bucket)
             t_pack1 = self.clock() if tr is not None else 0.0
             out = step(self.params, *packed)
+        attrs = None
         if tr is not None:
-            # queue_wait ends where packing starts; dispatch is the async
-            # step call only — the device window shows up as the gap
-            # between dispatch.t1 and the settle span
+            # queue_wait ends where packing starts; engine_lag is its part
+            # after the batch was ripe (or the request joined, if later);
+            # dispatch is the async step call only — the device window
+            # shows up as the gap between dispatch.t1 and the settle span
             t_disp = self.clock()
-            attrs = {"bucket": bucket, "round": self._round_no}
+            attrs = {"bucket": bucket, "round": self._round_no,
+                     "n_seeds": n_trees}
+            t_ripe = self.batcher.t_ripe
             for r in batch:
                 tr.extend(r.rid, (("queue_wait", r.t_ready, t_pack0, None),
+                                  ("engine_lag", max(t_ripe, r.t_ready),
+                                   t_pack0, None),
                                   ("bucket_pack", t_pack0, t_pack1, attrs),
                                   ("dispatch", t_pack1, t_disp, attrs)))
+            tr.stage("engine.pack", self._t_state, t_pack1, attrs)
+            tr.stage("engine.dispatch", t_pack1, t_disp, attrs)
+            self._t_state = t_disp
         with self._stats_lock:
             self.bucket_counts[bucket] += 1
             self.bucket_hits += int(self.steps.builds == warm)
-        self._inflight.append((batch, out))
+        self._inflight.append((batch, out, attrs))
         while len(self._inflight) > self.inflight_depth:
             self._finalize_one()
 
     def _finalize_one(self):
-        batch, out = self._inflight.popleft()
+        batch, out, attrs = self._inflight.popleft()
         out = np.asarray(out)                          # device sync
         now = self.clock()
         tr = self.tracer
+        if tr is not None:
+            tr.stage("engine.sync", self._t_state, now, attrs)
+            self._t_state = now
         settles = [] if tr is not None else None
         row = 0
         for req in batch:
@@ -517,6 +532,8 @@ class GNNServer:
             for r in batch:      # rid = exemplar = NeuraScope trace id
                 self._m_latency.observe(r.latency, exemplar=str(r.rid))
             self._m_requests.inc(len(batch), outcome="served")
+        if tr is not None:
+            self._mark("engine.scatter", attrs)
 
     def _pull_metrics(self):
         """Render-time gauge refresh — queue and cache state already lives
@@ -569,9 +586,36 @@ class GNNServer:
             with self._stats_lock:
                 self.n_deadline_failed += len(expired)
 
+    def _mark(self, name: str, attrs: Optional[dict] = None):
+        """Close the engine thread's current state span, now, as ``name``:
+        each state starts where the last ended, so the ``engine.*`` stage
+        spans tile the loop (tracing on only)."""
+        now = self.clock()
+        self.tracer.stage(name, self._t_state, now, attrs)
+        self._t_state = now
+
+    def _mark_wait(self, batch: Optional[List[ServeRequest]]):
+        """The blocking wait for a ripe batch, split where its oldest
+        request joined: ``engine.idle`` before (nothing pending, nothing in
+        flight), ``engine.form`` after (waiting for a trigger)."""
+        now = self.clock()
+        t_first = batch[0].t_ready if batch else self.batcher.oldest_ready()
+        t = now if t_first is None else min(max(t_first, self._t_state),
+                                            now)
+        if t > self._t_state:
+            self.tracer.stage("engine.idle", self._t_state, t)
+        if now > t:
+            self.tracer.stage("engine.form", t, now)
+        self._t_state = now
+
     def _engine_loop(self):
+        tr = self.tracer
+        if tr is not None:
+            self._t_state = self.clock()
         while not self._stop.is_set():
             self._reap_expired()
+            if tr is not None:
+                self._mark("engine.reap")
             if self._inflight:
                 # work is on the device: only grab a ripe batch, otherwise
                 # retire the oldest in-flight batch (its sync overlaps the
@@ -582,6 +626,8 @@ class GNNServer:
                     continue
             else:
                 batch = self.batcher.take(timeout=0.02)
+                if tr is not None:
+                    self._mark_wait(batch)
             if batch:
                 self._dispatch(batch)
         for batch in self.batcher.flush():
@@ -691,6 +737,8 @@ class GNNServer:
                                        {"error": "ServerClosed"})
         if self._metrics_server is not None:
             self._metrics_server.close()
+        if self.tracer is not None:
+            self.tracer.close()
 
     def __enter__(self):
         return self

@@ -12,12 +12,21 @@ flushes it as one ``{"kind": "trace", ...}`` line through the TelemetryHub
 JSONL flight recorder, sharing the time axis and ``schema_version`` with
 the event/sample records already there.
 
+Beside the request trees the tracer keeps the **engine's own time**: the
+single-lane engine thread records its loop as consecutive ``engine.*``
+stage spans (idle, form, pack, dispatch, sync, scatter, reap), and, while
+tracing is on, Python's collector adds ``host.gc`` spans.  Both go to a
+bounded ring (``stages``) sized like the trace ring.  ``anchor`` writes
+one ``serve.clock`` annotation into a running ``jax.profiler`` trace
+carrying the tracer's own time, so a reader of the profile can put every
+span on the device trace's clock.
+
 Cost model: tracing is **off by default** — engines built without it hold
 ``tracer = None`` and their hot loops carry a single ``is None`` test per
 stage (the chaos-injector convention).  Enabled, a span is one tuple
-append (no dict until flush); the serving benchmark gates the measured
-closed-loop overhead at ≤5% req/s (``tracing_overhead`` in
-``BENCH_serving.json``).
+append (no dict until flush).  On a TPU v5e, the chip benchmark's closed
+Reddit cell (``benchmarks/chip``) served 5,625 and 5,638 seeds/s traced
+against 5,622–5,673 untraced: under 1% when no host stall hits.
 
 The span *tree* is two-level by construction: the request is the implicit
 root and every span is its child, ordered by emission.  Exactly-once
@@ -29,6 +38,7 @@ reopening a flushed trace.
 from __future__ import annotations
 
 import collections
+import gc
 import threading
 import time
 from typing import Callable, Dict, List, Optional
@@ -43,8 +53,16 @@ TERMINAL_SPANS = ("settle", "error", "shed")
 
 # the canonical request lifecycle, in pipeline order (the waterfall's row
 # order; retry/reroute interleave wherever the failover machinery fired)
-STAGE_ORDER = ("submit", "route", "sample", "queue_wait", "bucket_pack",
-               "dispatch", "retry", "reroute", "settle", "error", "shed")
+STAGE_ORDER = ("submit", "route", "sample", "queue_wait", "engine_lag",
+               "bucket_pack", "dispatch", "retry", "reroute", "settle",
+               "error", "shed")
+
+# the engine thread's states, one ``stages`` span each, tiling its loop
+ENGINE_STATES = ("engine.idle", "engine.form", "engine.pack",
+                 "engine.dispatch", "engine.sync", "engine.scatter",
+                 "engine.reap")
+# the profiler annotation that ties the tracer's clock to the trace's
+CLOCK_MARK = "serve.clock"
 
 
 class Tracer:
@@ -77,6 +95,10 @@ class Tracer:
         self.n_spans = 0
         self.n_traces = 0
         self.n_dropped = 0                     # late spans against closed ids
+        # engine states and collector passes, not tied to one request
+        self._stages: "collections.deque" = collections.deque(
+            maxlen=self.capacity)
+        self._gc_t0: Optional[float] = None
 
     # -- hot path (engines guard every call with ``tracer is not None``) ----
     def span(self, trace: int, name: str, t0: float, t1: float,
@@ -106,6 +128,12 @@ class Tracer:
             lst = self._open.setdefault(trace, [])
         lst.extend(spans)
         self.n_spans += len(spans)
+
+    def stage(self, name: str, t0: float, t1: float,
+              attrs: Optional[dict] = None):
+        """Record one completed span of the process's own time (an engine
+        state, a collector pass) in the bounded stage ring."""
+        self._stages.append((name, t0, t1, attrs))
 
     def settle(self, trace: int, name: str, t0: float, t1: float,
                attrs: Optional[dict] = None):
@@ -173,6 +201,43 @@ class Tracer:
         with self._lock:
             snap = list(self._done)
         return [self.record(t, s) for t, s in snap]
+
+    def stages(self) -> List[dict]:
+        """Every stage span still in the ring, in the order recorded, on
+        the same ``t0``-relative axis as ``traces``."""
+        return self.record(None, list(self._stages))["spans"]
+
+    # -- one clock with the profiler -----------------------------------------
+    def anchor(self):
+        """Write one ``serve.clock`` annotation into the running
+        ``jax.profiler`` trace; its ``mono_ns`` stat is this tracer's time
+        (``t0``-relative, in ns) at the moment the annotation opens, so the
+        trace's start time minus ``mono_ns`` maps a span onto the trace."""
+        from jax.profiler import TraceAnnotation
+        mono_ns = int(round((self.clock() - self.t0) * 1e9))
+        with TraceAnnotation(CLOCK_MARK, mono_ns=mono_ns):
+            pass
+
+    # -- the collector -------------------------------------------------------
+    def watch_gc(self):
+        """Record each pass of Python's collector as a ``host.gc`` stage
+        span until ``close``."""
+        if self._on_gc not in gc.callbacks:
+            gc.callbacks.append(self._on_gc)
+
+    def _on_gc(self, phase: str, info: dict):
+        if phase == "start":
+            self._gc_t0 = self.clock()
+        elif self._gc_t0 is not None:
+            self.stage("host.gc", self._gc_t0, self.clock(),
+                       {"generation": info.get("generation"),
+                        "collected": info.get("collected")})
+            self._gc_t0 = None
+
+    def close(self):
+        """Stop recording the collector (the span rings stay readable)."""
+        if self._on_gc in gc.callbacks:
+            gc.callbacks.remove(self._on_gc)
 
     def open_traces(self) -> List[int]:
         return list(self._open)

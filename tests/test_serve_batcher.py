@@ -92,6 +92,28 @@ def test_deadline_trigger_fires_partial_batch():
     assert [r.rid for r in batch] == [0]
 
 
+@pytest.mark.parametrize("trigger", ["size", "deadline"])
+def test_take_stamps_when_the_batch_ripened(trigger):
+    """``t_ripe``: the fill moment under the size trigger, the oldest
+    request's ``t_ready + max_wait`` under the deadline trigger, whenever
+    the engine gets round to taking the batch."""
+    clk = Clock()
+    b = DynamicBatcher(max_seeds=4, max_wait=0.5, clock=clk)
+    b.submit(_req(0, k=2))                   # t_ready 0.0
+    clk.t = 0.1
+    b.submit(_req(1))
+    if trigger == "size":
+        clk.t = 0.3
+        b.submit(_req(2))                    # 4 seeds: full at 0.3
+        want = 0.3
+    else:
+        want = 0.0 + 0.5
+    clk.t = 0.9                              # the engine comes late
+    batch = b.poll()
+    assert batch and b.t_ripe == pytest.approx(want)
+    assert b.t_ripe <= clk.t
+
+
 def test_oversized_request_rejected():
     b = DynamicBatcher(max_seeds=4, max_wait=0.1, clock=Clock())
     with pytest.raises(ValueError):
@@ -169,6 +191,7 @@ def test_flush_drains_everything():
     batches = b.flush()
     assert [len(x) for x in batches] == [4, 4, 3]
     assert sorted(r.rid for x in batches for r in x) == list(range(11))
+    assert b.t_ripe <= clk.t                # an unripe flush ripens now
 
 
 # ---------------------------------------------------------------------------

@@ -8,6 +8,7 @@ nothing at all.  ``tracing.verify_trace``/``verify_traces`` is the single
 verifier shared with ``neurascope --check``, so a CI smoke failure and a
 test failure here always agree on what "well-formed" means.
 """
+import gc
 import json
 import os
 import time
@@ -18,8 +19,9 @@ import pytest
 from repro.launch.gnn_serve import build_world
 from repro.serve import (ChaosInjector, ClusterServer, GNNServer, LaneFault,
                          Overloaded, TelemetryHub, percentiles_ms)
-from repro.serve.tracing import (SCHEMA_VERSION, TERMINAL_SPANS, Tracer,
-                                 verify_trace, verify_traces)
+from repro.serve.tracing import (ENGINE_STATES, SCHEMA_VERSION,
+                                 TERMINAL_SPANS, Tracer, verify_trace,
+                                 verify_traces)
 
 try:
     from hypothesis import given, settings, strategies as st
@@ -98,6 +100,31 @@ def test_tracer_records_and_ring_bound():
     assert rec["schema_version"] == SCHEMA_VERSION
     assert rec["spans"][0] == {"name": "sample", "t0": 0.0, "t1": 1.0,
                                "lane": 0}
+
+
+def test_tracer_stage_ring_bound_and_collector_spans():
+    t = [0.0]
+    tr = Tracer(capacity=4, clock=lambda: t[0], t0=0.0)
+    for i in range(10):
+        tr.stage("engine.reap", float(i), i + 0.5, {"round": i})
+    st_ = tr.stages()
+    assert [s["round"] for s in st_] == [6, 7, 8, 9]    # newest kept
+    assert st_[0] == {"name": "engine.reap", "t0": 6.0, "t1": 6.5,
+                      "round": 6}
+    tr.watch_gc()
+    try:
+        t[0] = 20.0
+        tr._on_gc("start", {"generation": 2})
+        t[0] = 20.25
+        tr._on_gc("stop", {"generation": 2, "collected": 7})
+        gc.collect()                              # a real pass is recorded
+    finally:
+        tr.close()
+    assert tr._on_gc not in gc.callbacks
+    gcs = [s for s in tr.stages() if s["name"] == "host.gc"]
+    assert gcs[0] == {"name": "host.gc", "t0": 20.0, "t1": 20.25,
+                      "generation": 2, "collected": 7}
+    assert len(gcs) >= 2
 
 
 def test_tracer_drops_late_spans_after_settlement():
@@ -183,6 +210,7 @@ def test_tracer_property_random_interleavings(ids):
 # ---------------------------------------------------------------------------
 
 def test_tracing_disabled_allocates_nothing():
+    hooks = list(gc.callbacks)
     srv = _server(_world())
     with srv:
         assert srv.tracer is None
@@ -190,6 +218,10 @@ def test_tracing_disabled_allocates_nothing():
         srv.drain(timeout=120)
         assert all(r.error is None for r in reqs)
         assert "tracing" not in srv.stats()
+        # no stage span either: the engine keeps no state clock, and no
+        # collector hook is installed
+        assert not hasattr(srv, "_t_state")
+        assert gc.callbacks == hooks
     csrv = _cluster(_world(), tracing=False)
     with csrv:
         assert csrv.tracer is None
@@ -210,11 +242,46 @@ def test_engine_happy_path_span_trees():
         _assert_one_tree_per_request(srv.tracer, reqs)
         rec = srv.tracer.traces()[0]
         names = [s["name"] for s in rec["spans"]]
-        assert names == ["sample", "queue_wait", "bucket_pack", "dispatch",
-                         "settle"]
+        assert names == ["sample", "queue_wait", "engine_lag", "bucket_pack",
+                         "dispatch", "settle"]
         # stats surface for operators
         ts = srv.stats()["tracing"]
         assert ts["traces"] == 16 and ts["dropped"] == 0
+
+
+@pytest.mark.parametrize("sampler", ["host", "device"])
+def test_engine_states_tile_the_loop(sampler):
+    """The engine thread's ``engine.*`` stage spans follow one another with
+    no gap, in loop order; every dispatched round has one pack, one
+    dispatch and one sync; every request's ``engine_lag`` lies inside its
+    ``queue_wait``."""
+    srv = _server(_world(), tracing=True, sampler=sampler, max_wait_ms=2.0)
+    with srv:
+        reqs = [srv.submit([i % 256]) for i in range(12)]    # size trigger
+        for i in range(6):                                   # deadline
+            time.sleep(0.01)
+            reqs.append(srv.submit([(5 * i) % 256]))
+        srv.drain(timeout=120)
+    _assert_one_tree_per_request(srv.tracer, reqs)
+    eng = [s for s in srv.tracer.stages() if s["name"] != "host.gc"]
+    assert {s["name"] for s in eng} <= set(ENGINE_STATES)
+    assert {"engine.idle", "engine.pack", "engine.dispatch", "engine.sync",
+            "engine.scatter", "engine.reap"} <= {s["name"] for s in eng}
+    for a, b in zip(eng, eng[1:]):
+        assert b["t0"] == a["t1"] and b["t1"] >= b["t0"]
+    rounds = {}
+    for s in eng:
+        if "round" in s:
+            rounds.setdefault(s["round"], []).append(s["name"])
+    assert len(rounds) == srv.stats()["n_batches"]
+    for names in rounds.values():
+        assert names == ["engine.pack", "engine.dispatch", "engine.sync",
+                         "engine.scatter"]
+    for rec in srv.tracer.traces():
+        sp = {s["name"]: s for s in rec["spans"]}
+        q, lag = sp["queue_wait"], sp["engine_lag"]
+        assert q["t0"] <= lag["t0"] <= lag["t1"] == q["t1"]
+        assert sp["bucket_pack"]["n_seeds"] <= sp["bucket_pack"]["bucket"]
 
 
 def test_engine_deadline_expiry_yields_error_terminal():

@@ -74,7 +74,8 @@ from repro.serve.buckets import (all_buckets, bucket_for,
                                  build_bucket_structure, stack_trees)
 from repro.serve.compute import (CONV_ARCHS, FeatureStore, StepCache,
                                  _arch_key, build_fetch_step,
-                                 build_infer_step, build_lane_infer_step)
+                                 build_infer_step, build_lane_infer_step,
+                                 resident, row_major)
 from repro.serve.engine import SamplerPool, _needs_loops
 from repro.serve.errors import (DeadlineExceeded, DrainTimeout, LaneFailure,
                                 Overloaded, RetriesExhausted, SamplerError,
@@ -322,7 +323,7 @@ class ClusterServer:
         self._last_dispatch_t: Optional[float] = None
         self.indptr = np.asarray(indptr)
         self.indices = np.asarray(indices)
-        self.store = store
+        self.store = resident(store)
         self.n_lanes = int(n_lanes)
         self.mode = mode
         self.placement = placement
@@ -464,9 +465,9 @@ class ClusterServer:
             self.shard_plan = plan_feature_sharding(n_rows, self.n_lanes,
                                                     shard_gamma)
             x_perm = self.shard_plan.permute_table(np.asarray(self.store.x))
-            self._x_perm = jax.device_put(
+            self._x_perm = row_major(jax.device_put(
                 jax.numpy.asarray(x_perm),
-                NamedSharding(self.mesh, P("lane")))
+                NamedSharding(self.mesh, P("lane"))))
             self._perm_dev = jax.numpy.asarray(
                 self.shard_plan.perm.astype(np.int32))
             self._halo = jax.jit(make_halo_gather(
@@ -1041,12 +1042,12 @@ class ClusterServer:
             raise ValueError(f"feature row ids out of range [0, {n})")
         x = np.asarray(self.store.x).copy()
         x[row_ids] = rows
-        self.store = _dc.replace(self.store, x=jnp.asarray(x))
+        self.store = resident(_dc.replace(self.store, x=jnp.asarray(x)))
         if self.mode == "sharded":
             perm_rows = jnp.asarray(
                 self.shard_plan.perm[row_ids].astype(np.int32))
-            self._x_perm = jax.block_until_ready(
-                self._x_perm.at[perm_rows].set(jnp.asarray(rows)))
+            self._x_perm = jax.block_until_ready(row_major(
+                self._x_perm.at[perm_rows].set(jnp.asarray(rows))))
         else:
             self._fetch_step = build_fetch_step(self.store)
         # offline-replay parity anchor closes over the store at build time;

@@ -26,10 +26,12 @@ from typing import Callable, Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Format, Layout
 
 from repro.serve.buckets import BucketStructure, build_bucket_structure
 from repro.sparse.graph import chunk_width_multiple
 from repro.sparse.plan import make_plan, plan_with_values
+from repro.sparse.stats import record_count
 
 Array = jax.Array
 
@@ -68,12 +70,12 @@ class FeatureStore:
         def ghost(a, fill=0):
             pad = np.full((1,) + a.shape[1:], fill, a.dtype)
             return jnp.asarray(np.concatenate([a, pad]))
-        return FeatureStore(
+        return resident(FeatureStore(
             n_nodes=n_nodes,
             x=None if x is None else ghost(np.asarray(x, np.float32)),
             species=(None if species is None
                      else ghost(np.asarray(species, np.int32))),
-            pos=None if pos is None else ghost(np.asarray(pos, np.float32)))
+            pos=None if pos is None else ghost(np.asarray(pos, np.float32))))
 
     def row_index(self, node_ids: Array) -> Array:
         return jnp.where(node_ids >= 0, node_ids, self.n_nodes).astype(
@@ -86,6 +88,47 @@ class FeatureStore:
 jax.tree_util.register_dataclass(FeatureStore,
                                  data_fields=["x", "species", "pos"],
                                  meta_fields=["n_nodes"])
+
+
+def row_major(x: Optional[Array]) -> Optional[Array]:
+    """``x`` committed in the row-major layout a row gather reads.
+
+    A TPU lays a narrow 2-D table out column-major by default (it pads
+    least: Reddit's 602 columns would pad to 640 row-major), and a jitted
+    step that gathers rows from such an argument relays the whole table
+    out on every call.  A table already row-major — always so on the CPU
+    — comes back as the same object; otherwise it is re-committed once on
+    its device and ``feature_store.relayouts`` counts it.  ``jit`` then
+    compiles against the committed layout, with no copy in the step.
+    """
+    layout = getattr(getattr(x, "format", None), "layout", None)
+    if layout is None:
+        return x
+    order = tuple(range(x.ndim))
+    if layout.major_to_minor == order:
+        return x
+    record_count("feature_store.relayouts")
+    return jax.jit(_relayout, out_shardings=Format(
+        Layout(major_to_minor=order), x.sharding))(x)
+
+
+def _relayout(a):
+    # JAX's persistent compilation cache loads a program back with its
+    # outputs reported in the default layout, so a table this program wrote
+    # in a later process would lie in one layout and be read in another by
+    # every jit after it (a size fault on a TPU, wrong rows on the CPU).  A
+    # host callback keeps this one program out of that cache; the steps
+    # that read the table are cached as usual, since argument layouts load
+    # back intact.
+    jax.debug.callback(lambda: None)
+    return a
+
+
+def resident(store: FeatureStore) -> FeatureStore:
+    """``store`` with its tables of rank 2 committed row-major (not in
+    ``__post_init__``: jit rebuilds the dataclass on tracers)."""
+    return dataclasses.replace(store, x=row_major(store.x),
+                               pos=row_major(store.pos))
 
 
 # ---------------------------------------------------------------------------

@@ -41,7 +41,7 @@ from repro.serve.errors import (DeadlineExceeded, DrainTimeout,
                                 RetriesExhausted, SamplerError, ServeError,
                                 ServerClosed, TransientStepError)
 from repro.serve.compute import (FeatureStore, StepCache, _arch_key,
-                                 build_infer_step)
+                                 build_infer_step, resident)
 from repro.serve.telemetry import percentiles_ms
 from repro.serve.tracing import Tracer
 from repro.sparse import sampler
@@ -237,7 +237,8 @@ class GNNServer:
         self.params = params
         self.indptr = np.asarray(indptr)
         self.indices = np.asarray(indices)
-        self.store = store
+        # committed row-major once: the steps gather rows from it
+        self.store = resident(store)
         self.fanouts = tuple(int(f) for f in fanouts)
         self.backend = backend
         self.max_batch_seeds = int(max_batch_seeds)

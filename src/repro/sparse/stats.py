@@ -13,6 +13,8 @@ Plan builders and kernels record into one process-global registry:
 * ``q8.*``      — per-chunk quantization scales (the scale *is* the error
   bound's knob: per-entry rounding ≤ scale/2);
 * ``drhm.*``    — shard-/routing-plan builds and bin-balance snapshots.
+* ``feature_store.*`` — ``relayouts``: resident tables re-committed
+  row-major (``serve.compute.row_major``), once per table a server takes.
 
 Everything here is host-side bookkeeping on paths that run once per plan
 (never per step), so the cost budget is "does not matter"; recording is

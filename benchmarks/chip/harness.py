@@ -4,12 +4,15 @@ Everything that belongs to one configuration, traffic mix or metric lives
 in a file of its own, found by the name ``BENCHMARK.json`` gives it:
 
 * ``configs/<config>.json`` — sizes and serving settings;
+* ``archs/<arch>.py`` — what one architecture needs, by the config's
+  ``"arch"``: the program's model config, weights, plain reference and
+  work counts (``archs/__init__.py``);
 * ``traffic/<traffic>.json`` — parameters that ``loadgen`` reads;
 * ``metrics/<metric>.py`` — ``read(ctx) -> float | None``;
 * ``peaks.json`` — the chip's peaks, keyed by ``device_kind``.
 
-The system under test is ``repro.serve.GNNServer`` (GraphSAGE, Pallas
-backend, sampling fused into the device step), driven through ``submit``.
+The system under test is ``repro.serve.GNNServer`` (the configuration's
+architecture, backend and sampler), driven through ``submit``.
 """
 from __future__ import annotations
 
@@ -18,14 +21,15 @@ import dataclasses
 import importlib.util
 import json
 import os
+import subprocess
 import sys
 import time
 from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
-from benchmarks.chip import loadgen, reference, sampling, tracereduce
-from benchmarks.chip import worldgen
+from benchmarks.chip import archs, loadgen, reference, sampling
+from benchmarks.chip import tracereduce, worldgen
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
@@ -76,6 +80,10 @@ def load_cell(workload: str, root: str = ROOT) -> Cell:
     w = cells[workload]
     configs = {c["name"]: c for c in bench["configs"]}
     config = _json(os.path.join(root, configs[w["config"]]["file"]))
+    if not os.path.isfile(archs.path(config["arch"])):
+        raise HarnessError(f"configuration {w['config']!r} names arch "
+                           f"{config['arch']!r}, and there is no "
+                           f"archs/{config['arch']}.py in {archs.DIR}")
     traffic = _json(os.path.join(HERE, "traffic", w["traffic"] + ".json"))
 
     def mine(m):
@@ -126,6 +134,28 @@ def memory_peak_bytes() -> Optional[int]:
     return max(peaks) if peaks else None
 
 
+def warm_in_child(cell: Cell, seed: int, cache_dir: str = CACHE_DIR
+                  ) -> None:
+    """Fill the persistent cache for ``cell`` from a process of its own,
+    once per checkout, before this process touches the device.  A process
+    that compiled its programs itself runs its window some percent slower
+    than one that loaded them from the cache (PERF.md §6), so every
+    measured process loads them.  The child is ``run.py --warm-only``; a
+    marker in the cache directory says it has run."""
+    mark = os.path.join(cache_dir, f"chipbench-warm-{cell.name}")
+    if os.path.exists(mark):
+        return
+    cmd = [sys.executable, os.path.join(HERE, "run.py"), "--workload",
+           cell.name, "--seed", str(seed), "--seconds", "0", "--warm-only"]
+    log(f"cache not yet filled for {cell.name}: set-up in a child process")
+    rc = subprocess.run(cmd, stdout=sys.stderr).returncode
+    if rc != 0:
+        raise HarnessError(f"the warm-up process exited with {rc}")
+    os.makedirs(cache_dir, exist_ok=True)
+    with open(mark, "w") as f:
+        f.write(f"{seed}\n")
+
+
 def enable_compile_cache(cache_dir: str = CACHE_DIR) -> None:
     """JAX's persistent cache at a fixed path inside the checkout."""
     import jax
@@ -158,15 +188,12 @@ class CompileCounter:
 
 def build_server(cfg: dict, world, csr: tuple, seed: int,
                  tracing: bool = False, trace_capacity: int = 4096):
-    from repro.models.gnn.sage import SAGEConfig
     from repro.serve import GNNServer
     from repro.serve.compute import FeatureStore
-    scfg = SAGEConfig(name=cfg["name"], n_layers=cfg["n_layers"],
-                      d_in=cfg["d_in"], d_hidden=cfg["d_hidden"],
-                      n_classes=cfg["n_classes"], param_dtype=cfg["dtype"])
     indptr, indices = csr
     serving = cfg["serving"]
-    return GNNServer(cfg["arch"], scfg, world.params, indptr, indices,
+    return GNNServer(cfg["arch"], archs.of(cfg).model_config(cfg),
+                     world.params, indptr, indices,
                      FeatureStore(n_nodes=world.n_nodes, x=world.x),
                      fanouts=cfg["fanouts"], backend=serving["backend"],
                      sampler=serving["sampler"],
@@ -294,11 +321,11 @@ def compare(cfg: dict, world, csr: tuple, server_seed: int, sample: list,
                                           cfg["fanouts"], server_seed)
     got = np.concatenate([np.asarray(h.result, np.float32) for h in sample])
     params = jax.tree.map(np.asarray, world.params)
-    ref = reference.reference_outputs(world.x, params, levels, valid,
-                                      cfg["fanouts"], "f32")
+    ref = reference.reference_outputs(cfg, world.x, params, levels, valid,
+                                      "f32")
     if compute != "f32":
-        got = reference.reference_outputs(world.x, params, levels, valid,
-                                          cfg["fanouts"], compute)
+        got = reference.reference_outputs(cfg, world.x, params, levels,
+                                          valid, compute)
     rms = max(float(np.sqrt(np.mean(ref.astype(np.float64) ** 2))), 1e-30)
     gap = np.abs(got.astype(np.float64) - ref)
     exact = got == got.astype(jnp.bfloat16).astype(np.float32)
@@ -328,7 +355,6 @@ def metric_context(cell: Cell, win: Window, setup_s: float, dev: dict,
             "t_end": win.t_end, "seconds": win.t_end - win.t0,
             "requests": window_requests(win), "batches": win.batches,
             "seeds_submitted": win.seeds_submitted,
-            "dims": worldgen.layer_dims(cell.config),
             "trace": summary, "spans": spans}
 
 
@@ -359,23 +385,10 @@ def profile_options():
     return opts
 
 
-def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
-             t_start: float, require_tpu: bool = True,
-             compile_cache: bool = True) -> dict:
-    """One run of ``cell``: returns the result line as a dict.
-    ``require_tpu=False`` and ``compile_cache=False`` are for the CPU
-    tests, which drive a tiny cell through the same run."""
-    import shutil
-    import tempfile
-
-    import jax
-    if compile_cache:
-        enable_compile_cache()
-    dev = device_info(cell.chips, require_tpu)
-    log(f"{dev['count']} x {dev['platform']} ({dev['kind']}), jax "
-        f"{jax.__version__}, cell {cell.name}, seed {seed}, {seconds} s, "
-        f"trace {int(trace)}")
-    counter = CompileCounter()
+def set_up(cell: Cell, seed: int, t_start: float, trace: bool = False,
+           seconds: float = 0.0):
+    """The cell's device data, host CSR copy and a server warmed on every
+    bucket its traffic uses: ``(world, csr, server)``."""
     cfg, traffic = cell.config, cell.traffic
     t = time.monotonic()
     world = worldgen.make_world(cfg, seed)
@@ -391,6 +404,38 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     log(f"set-up: device data {t_world:.2f} s, host CSR copy {t_csr:.2f} "
         f"s, warm-up of buckets {buckets} {time.monotonic() - t:.2f} s; "
         f"{time.monotonic() - t_start:.2f} s since start")
+    return world, csr, server
+
+
+def warm_cell(cell: Cell, seed: int, t_start: float) -> None:
+    """``run.py --warm-only``: the set-up alone, so that its programs land
+    in the persistent cache."""
+    enable_compile_cache()
+    device_info(cell.chips)
+    server = set_up(cell, seed, t_start)[2]
+    server.close()
+
+
+def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
+             t_start: float, require_tpu: bool = True,
+             compile_cache: bool = True) -> dict:
+    """One run of ``cell``: returns the result line as a dict.
+    ``require_tpu=False`` and ``compile_cache=False`` are for the CPU
+    tests, which drive a tiny cell through the same run."""
+    import shutil
+    import tempfile
+
+    import jax
+    if compile_cache:
+        enable_compile_cache()
+        warm_in_child(cell, seed)
+    dev = device_info(cell.chips, require_tpu)
+    log(f"{dev['count']} x {dev['platform']} ({dev['kind']}), jax "
+        f"{jax.__version__}, cell {cell.name}, seed {seed}, {seconds} s, "
+        f"trace {int(trace)}")
+    counter = CompileCounter()
+    cfg, traffic = cell.config, cell.traffic
+    world, csr, server = set_up(cell, seed, t_start, trace, seconds)
     log_dir = tempfile.mkdtemp(prefix="chipbench-trace-") if trace else None
     try:
         if trace:

@@ -9,7 +9,7 @@ from typing import List, Optional, Tuple
 
 import numpy as np
 
-from benchmarks.chip import workcount
+from benchmarks.chip import archs, workcount
 
 # the jitted bucket step (sampling, gather, aggregation, dense layers) on
 # the trace's ``XLA Modules`` line
@@ -87,9 +87,11 @@ def gustavson_roofline(ctx: dict) -> Optional[float]:
 
     Device time: every call of the kernel in the traced window.  Work: what
     the algorithm's aggregations need for the seeds those calls served
-    (``workcount.aggregation_floor_s``).  The trace spans the window and
-    the settling of every request sent in it, so its calls serve the seeds
-    submitted (``seeds_submitted``), one call per layer and batch."""
+    (``workcount.aggregation_floor_s`` over the architecture's
+    ``aggregation_work``).  The trace spans the window and the settling of
+    every request sent in it, so its calls serve the seeds submitted
+    (``seeds_submitted``): ``kernel_calls_per_layer`` calls per layer and
+    batch."""
     tr, peaks = ctx.get("trace"), ctx.get("peaks")
     if tr is None or not peaks or not ctx["batches"]:
         return None
@@ -99,20 +101,29 @@ def gustavson_roofline(ctx: dict) -> Optional[float]:
     sec = sum(s for _, s in calls)
     if not n or sec <= 0:
         return None
-    fanouts = ctx["config"]["fanouts"]
-    seeds = n / len(fanouts) * ctx["seeds_submitted"] / ctx["batches"]
+    cfg = ctx["config"]
+    arch = archs.of(cfg)
+    per_batch = len(cfg["fanouts"]) * arch.kernel_calls_per_layer(cfg)
+    seeds = n / per_batch * ctx["seeds_submitted"] / ctx["batches"]
     floor = seeds * workcount.aggregation_floor_s(
-        fanouts, ctx["dims"], peaks["bf16_flops"], peaks["hbm_bytes_per_s"])
+        arch.aggregation_work(cfg), peaks["bf16_flops"],
+        peaks["hbm_bytes_per_s"])
     return 100.0 * floor / sec
 
 
+def flops_per_seed(ctx: dict) -> int:
+    """FLOPs the configuration's algorithm needs per seed
+    (``archs/<arch>.py``)."""
+    cfg = ctx["config"]
+    return archs.of(cfg).flops_per_seed(cfg)
+
+
 def step_mfu(ctx: dict) -> Optional[float]:
-    """FLOPs that GraphSAGE's minibatch algorithm needs for the seeds the
-    traced steps served (``workcount``), over the steps' device time times
-    the chip's bf16 peak, in %."""
+    """FLOPs that the architecture's minibatch algorithm needs for the
+    seeds the traced steps served (``flops_per_seed``), over the steps'
+    device time times the chip's bf16 peak, in %."""
     step, peaks = fused_step(ctx), ctx.get("peaks")
     if step is None or not peaks or not ctx["seeds_submitted"]:
         return None
-    flops = workcount.flops_per_seed(ctx["config"]["fanouts"], ctx["dims"])
-    return (100.0 * flops * ctx["seeds_submitted"] / step[1]
+    return (100.0 * flops_per_seed(ctx) * ctx["seeds_submitted"] / step[1]
             / peaks["bf16_flops"])

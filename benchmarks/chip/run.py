@@ -33,6 +33,9 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--warm-only", action="store_true",
+                    help="only the set-up, to fill the compile cache; "
+                         "prints no result")
     args = ap.parse_args(argv)
     try:
         import repro.serve  # noqa: F401 — the system under test
@@ -43,6 +46,9 @@ def main(argv=None) -> int:
         return 2
     try:
         cell = harness.load_cell(args.workload)
+        if args.warm_only:
+            harness.warm_cell(cell, args.seed, T_START)
+            return 0
         out = harness.run_cell(cell, args.seed, args.seconds,
                                bool(args.trace), T_START)
     except harness.HarnessError as exc:

@@ -14,7 +14,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 sys.path[:0] = [ROOT, os.path.join(ROOT, "src")]
 
 from benchmarks.chip import enginetrace as et  # noqa: E402
-from benchmarks.chip import harness, tracereduce, worldgen  # noqa: E402
+from benchmarks.chip import harness, tracereduce  # noqa: E402
 from benchmarks.chip.tests import test_chipbench_trace as recorded  # noqa
 
 E = tracereduce.Event
@@ -261,7 +261,7 @@ def test_accepted_trace_metrics_are_unchanged(trace, metric):
     with open(os.path.join(ROOT, "benchmarks/chip/peaks.json")) as f:
         peaks = json.load(f)["devices"]["TPU v5 lite"]
     ctx = {"trace": tracereduce.summarize(planes), "peaks": peaks,
-           "config": cfg, "dims": worldgen.layer_dims(cfg), "batches": 2,
+           "config": cfg, "batches": 2,
            "seeds_submitted": 32}
     want = PINNED[trace][metric]
     got = harness.reader(metric)(ctx)

@@ -54,6 +54,21 @@ from repro.core import platform
 DEFAULT_GROUP = 8        # landing-buffer rows per DMA wave (MMH4 lane count)
 MAX_SINGLE_TILE_D = 1024  # auto d_tile: keep one feature tile up to this width
 LANES = 128              # TPU lane tile: compiled chunk widths / d_tiles
+# one v5e TensorCore's scalar memory, which the scalar-prefetched chunk
+# tables share with the compiler's own scalars (hence the reserve)
+SMEM_BYTES = 1 << 20
+SMEM_RESERVE = 64 << 10
+
+
+def tables_fit_smem(u_cols) -> bool:
+    """Whether a layout's chunk tables fit the SMEM that the compiled kernel
+    prefetches them into: ``u_cols`` and at most four 32-bit scalars a
+    chunk (``remaining``, ``out_block``, ``first`` and, in ``pallas_q8``,
+    the chunk's scale).  The interpreter has no SMEM to fill."""
+    if platform.interpret_mode():
+        return True
+    words = u_cols.size + 4 * u_cols.shape[0]
+    return 4 * words <= SMEM_BYTES - SMEM_RESERVE
 
 
 def _fold(a_ref, first_smem, y_ref, land, k):

@@ -11,8 +11,9 @@ layout (seeds occupy slots ``0..k-1``).
 
 The structure also carries what the models need beyond raw hops:
 
-* optional **self-loop** edges (GCN's ``A + I`` normalization) appended
-  after the hop edges — their validity is ``node_ids >= 0``, traced;
+* optional **self-loop** edges (GCN's ``A + I`` normalization, GAT's
+  attention over a node's own row) appended after the hop edges — their
+  validity is ``node_ids >= 0``, traced;
 * **triplet** indices for DimeNet: the trees make every sampled node's
   in-edges consecutive, so ``(t_in, t_out)`` are again pure arange
   arithmetic; only ``t_valid = valid[t_in] & valid[t_out]`` is traced.

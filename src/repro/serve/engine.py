@@ -52,7 +52,9 @@ _DEVICE_SAMPLE_ATTRS = {"mode": "device"}
 
 
 def _needs_loops(arch_id: str) -> bool:
-    return _arch_key(arch_id) == "gcn"
+    """GCN normalises ``A + I``; GAT attends over each node's own row too
+    (``GATConv``'s ``add_self_loops``)."""
+    return _arch_key(arch_id) in ("gcn", "gat")
 
 
 def default_tree_keys(rid: int, n: int) -> np.ndarray:

@@ -189,7 +189,9 @@ def make_plan(senders: np.ndarray, receivers: np.ndarray, n_rows: int,
 
     Only valid edges enter the pallas/distributed layouts; invalid (padding)
     edges get an out-of-bounds scatter slot, so traced per-edge values on
-    padding lanes are dropped by construction.
+    padding lanes are dropped by construction.  ``block_rows`` is the least
+    output-block height: compiled, a layout whose chunk tables would not
+    fit the kernel's SMEM packs blocks twice as tall until they do.
     """
     for b in backends:
         if b not in ALL_BACKENDS:
@@ -208,17 +210,26 @@ def make_plan(senders: np.ndarray, receivers: np.ndarray, n_rows: int,
               valid=jnp.asarray(valid), base_vals=jnp.asarray(base))
 
     if "pallas" in backends or "pallas_q8" in backends:
+        from repro.kernels.gustavson_spmm.gustavson_spmm import (
+            tables_fit_smem)
         from repro.sparse.graph import pack_dedup_chunks
         from repro.sparse.stats import record_count, record_value
-        pack_kw = dict(block_rows=block_rows, width_cap=width_cap,
-                       width_multiple=width_multiple)
-        # forward (A) and transpose (Aᵀ — the kernelized backward's layout);
-        # the matrix is square over the padded node space, so the transpose
-        # is the same packer with sender/receiver roles swapped
-        fwd = pack_dedup_chunks(r[vidx], s[vidx], base[vidx], int(n_rows),
-                                int(n_rows), **pack_kw)
-        tr = pack_dedup_chunks(s[vidx], r[vidx], base[vidx], int(n_rows),
-                               int(n_rows), **pack_kw)
+        while True:
+            pack_kw = dict(block_rows=block_rows, width_cap=width_cap,
+                           width_multiple=width_multiple)
+            # forward (A) and transpose (Aᵀ — the kernelized backward's
+            # layout); the matrix is square over the padded node space, so
+            # the transpose is the same packer with the roles swapped
+            fwd = pack_dedup_chunks(r[vidx], s[vidx], base[vidx],
+                                    int(n_rows), int(n_rows), **pack_kw)
+            tr = pack_dedup_chunks(s[vidx], r[vidx], base[vidx],
+                                   int(n_rows), int(n_rows), **pack_kw)
+            # the compiled kernel holds a layout's chunk tables in SMEM;
+            # where they would not fit, taller row blocks need fewer chunks
+            if block_rows >= n_rows or (tables_fit_smem(fwd.u_cols)
+                                        and tables_fit_smem(tr.u_cols)):
+                break
+            block_rows *= 2
         record_count("plan.dedup_packs", 2)
         record_value("plan.chunk_width", fwd.u_cols.shape[1])
         record_value("plan.n_chunks", fwd.u_cols.shape[0])

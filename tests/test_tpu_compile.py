@@ -8,8 +8,11 @@ at import: only one process may load the TPU library, and every xdist
 worker imports this file.  The program asks ``core.platform.on_tpu`` which
 backend it is on; each test steers that one check itself.
 """
+import re
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
@@ -149,3 +152,76 @@ def test_fused_sage_serving_step_compiles(one_chip, compile_for_tpu):
         server.close()
     # hop-1 and hop-2 draws + two SAGE layers' aggregations
     assert text.count("tpu_custom_call") >= 4
+
+
+def _lower_fused_gat_step(one_chip):
+    """The 16-seed bucket program of ``GNNServer("gat", backend="pallas",
+    sampler="device")`` at ogbn-products GAT widths (100 -> 4x128 -> 4x128
+    -> 47, last heads averaged, skips, fanouts (10, 10, 10)), lowered for
+    one chip: the draw kernels, and per layer the attention and one valued
+    Gustavson kernel per head."""
+    from repro.data import synthetic as syn
+    from repro.models.gnn import gat
+    from repro.serve import GNNServer
+    from repro.serve.compute import FeatureStore
+    from repro.sparse.graph import coo_to_csr
+    cfg = gat.GATConfig(name="gat-products", n_layers=3, d_in=100,
+                        d_hidden=128, n_heads=4, n_classes=47, out_heads=4,
+                        skip=True)
+    params = gat.init_params(jax.random.key(0), cfg)
+    s, r = syn.powerlaw_graph(300, 3000, seed=0)
+    indptr, indices = coo_to_csr(s, r, 300)[:2]
+    x = np.random.default_rng(0).normal(size=(300, 100)).astype(np.float32)
+    server = GNNServer("gat", cfg, params, indptr, indices,
+                       FeatureStore.build(300, x=x), fanouts=(10, 10, 10),
+                       backend="pallas", sampler="device",
+                       max_batch_seeds=16)
+    try:
+        step = server.steps.get((16,))
+        bound = jax.tree.map(lambda a: _spec(a, one_chip),
+                             (step.args, params))
+        seeds = jax.ShapeDtypeStruct((16,), jnp.int32, sharding=one_chip)
+        keys = jax.ShapeDtypeStruct((16,), jnp.uint32, sharding=one_chip)
+        live = jax.ShapeDtypeStruct((16,), jnp.bool_, sharding=one_chip)
+        return step.func.lower(*bound[0], bound[1], seeds, keys, keys, live)
+    finally:
+        server.close()
+
+
+def test_fused_gat_serving_step_compiles(one_chip, compile_for_tpu):
+    text = _lower_fused_gat_step(one_chip).compile().as_text()
+    kernels = re.findall(r"custom_call_target=\"tpu_custom_call\".*?"
+                         r"op_name=\"([^\"]*)\"", text)
+    gustavson = [k for k in kernels if "layer" in k and ".aggregate" in k]
+    # one draw kernel per hop, and 3 layers x 4 heads of aggregation
+    assert len(kernels) == 3 + 12
+    assert sorted({k.split("/")[1] for k in gustavson}) == [
+        "layer0.aggregate", "layer1.aggregate", "layer2.aggregate"]
+    assert len(gustavson) == 12
+
+
+def test_fused_gat_step_carries_the_stage_scopes(one_chip, compile_for_tpu):
+    text = _lower_fused_gat_step(one_chip).as_text(debug_info=True)
+    for k in range(3):
+        for stage in ("attend", "aggregate", "dense"):
+            assert f"layer{k}.{stage}" in text, (k, stage)
+    assert "serve.sample" in text and "serve.gather" in text
+
+
+@pytest.mark.parametrize("fanouts,loops,block_rows", [
+    ((25, 10), False, 8),          # sage-reddit
+    ((15, 10, 5), False, 8),       # sage-products
+    ((10, 10, 10), True, 16),      # gat-products: 17,776 rows and loops
+])
+def test_bucket_plans_keep_their_chunk_tables_in_smem(compile_for_tpu,
+                                                      fanouts, loops,
+                                                      block_rows):
+    """Compiled, the kernel prefetches a layout's chunk tables into SMEM:
+    a 16-seed bucket whose tables would not fit packs taller row blocks,
+    and the GraphSAGE buckets keep the blocks they had."""
+    from repro.kernels.gustavson_spmm.gustavson_spmm import tables_fit_smem
+    plan = bucket_plan(build_bucket_structure(16, fanouts, with_loops=loops),
+                       "pallas", True)
+    assert plan.block_rows == block_rows
+    assert tables_fit_smem(plan.ell_u_cols)
+    assert tables_fit_smem(plan.ell_t_u_cols)

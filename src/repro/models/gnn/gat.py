@@ -19,13 +19,13 @@ softmax) and ``layer{k}.aggregate`` (the per-head SpMMs), as GraphSAGE's.
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from typing import Optional, Sequence, Union
 
 import jax
 import jax.numpy as jnp
 
 from repro.sparse import backend as sb
-from repro.sparse.plan import AggregationPlan, edge_plan
+from repro.sparse.plan import AggregationPlan, edge_plan, per_layer
 from repro.sparse.segment_ops import segment_softmax
 
 Array = jax.Array
@@ -83,14 +83,16 @@ def init_params(key, cfg: GATConfig):
 
 def gat_layer(p, cfg: GATConfig, x: Array, pl: AggregationPlan,
               last: bool, backend: str, name: str) -> Array:
-    n = x.shape[0]
+    """Rows ``[0, pl.out_rows)`` of the layer's output, from every row of
+    ``x`` (the source transform covers each row an edge reads)."""
+    n = pl.out_rows
     senders, receivers, edge_valid = pl.cols, pl.rows, pl.valid
     with jax.named_scope(f"{name}.dense"):
         h = _pin(jnp.einsum("nd,dhf->nhf", x, p["w"].astype(x.dtype)), cfg)
     # SDDMM stage: per-edge attention logits, softmax over each row's edges
     with jax.named_scope(f"{name}.attend"):
         e_src = (h * p["a_src"].astype(x.dtype)).sum(-1)       # (N, H)
-        e_dst = (h * p["a_dst"].astype(x.dtype)).sum(-1)
+        e_dst = (h[:n] * p["a_dst"].astype(x.dtype)).sum(-1)
         logits = jax.nn.leaky_relu(
             jnp.take(e_src, senders, axis=0)
             + jnp.take(e_dst, receivers, axis=0),
@@ -110,7 +112,7 @@ def gat_layer(p, cfg: GATConfig, x: Array, pl: AggregationPlan,
         out = agg.mean(axis=1) if last else agg.reshape(n, -1)
         out = out + p["b"].astype(x.dtype)
         if cfg.skip:
-            out = (out + x @ p["w_skip"].astype(x.dtype)
+            out = (out + x[:n] @ p["w_skip"].astype(x.dtype)
                    + p["b_skip"].astype(x.dtype))
         if not last:
             out = jax.nn.elu(out)
@@ -120,11 +122,12 @@ def gat_layer(p, cfg: GATConfig, x: Array, pl: AggregationPlan,
 def forward(params, cfg: GATConfig, x: Array, senders: Array = None,
             receivers: Array = None, edge_valid: Array = None,
             backend: str = "dense",
-            plan: Optional[AggregationPlan] = None) -> Array:
-    pl = plan if plan is not None else edge_plan(
-        senders, receivers, x.shape[0], edge_valid=edge_valid)
+            plan: Union[AggregationPlan, Sequence[AggregationPlan]] = None
+            ) -> Array:
+    plans = per_layer(plan if plan is not None else edge_plan(
+        senders, receivers, x.shape[0], edge_valid=edge_valid), cfg.n_layers)
     h = x
-    for i in range(cfg.n_layers):
+    for i, pl in enumerate(plans):
         h = gat_layer(params[f"layer{i}"], cfg, h, pl,
                       last=i == cfg.n_layers - 1, backend=backend,
                       name=f"layer{i}")

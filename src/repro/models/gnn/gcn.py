@@ -11,13 +11,13 @@ the traced edge arrays; ``pallas``/``distributed`` need a host-built
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from typing import Optional, Sequence, Union
 
 import jax
 import jax.numpy as jnp
 
 from repro.sparse import backend as sb
-from repro.sparse.plan import AggregationPlan, edge_plan
+from repro.sparse.plan import AggregationPlan, edge_plan, per_layer
 
 Array = jax.Array
 
@@ -62,17 +62,20 @@ def init_params(key, cfg: GCNConfig):
 def forward(params, cfg: GCNConfig, x: Array, senders: Array = None,
             receivers: Array = None, edge_weight: Optional[Array] = None,
             edge_valid: Array = None, backend: str = "dense",
-            plan: Optional[AggregationPlan] = None) -> Array:
-    """x: (N_pad, d_in) — returns logits (N_pad, n_classes).
+            plan: Union[AggregationPlan, Sequence[AggregationPlan]] = None
+            ) -> Array:
+    """x: (N_pad, d_in) — returns logits (N_pad, n_classes), or with one
+    plan a layer the last plan's ``out_rows`` of them.
 
     Aggregation direction: receivers accumulate sender features (rows =
     receivers, cols = senders) — one Gustavson SpMM per layer, dispatched on
     the named backend.
     """
-    pl = plan if plan is not None else edge_plan(
-        senders, receivers, x.shape[0], edge_weight, edge_valid)
+    plans = per_layer(plan if plan is not None else edge_plan(
+        senders, receivers, x.shape[0], edge_weight, edge_valid),
+        cfg.n_layers)
     h = x
-    for i in range(cfg.n_layers):
+    for i, pl in enumerate(plans):
         p = params[f"layer{i}"]
         h = _pin_nodes(h @ p["w"].astype(h.dtype), cfg)   # combination (dense)
         h = sb.aggregate(pl, None, h, backend=backend)    # aggregation

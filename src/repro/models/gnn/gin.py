@@ -9,14 +9,14 @@ backend engine.
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from typing import Optional, Sequence, Union
 
 import jax
 import jax.numpy as jnp
 
 from repro.models.common import mlp_apply, mlp_init
 from repro.sparse import backend as sb
-from repro.sparse.plan import AggregationPlan, edge_plan
+from repro.sparse.plan import AggregationPlan, edge_plan, per_layer
 
 Array = jax.Array
 
@@ -54,14 +54,16 @@ def init_params(key, cfg: GINConfig):
 def forward(params, cfg: GINConfig, x: Array, senders: Array = None,
             receivers: Array = None, edge_valid: Array = None,
             backend: str = "dense",
-            plan: Optional[AggregationPlan] = None) -> Array:
-    pl = plan if plan is not None else edge_plan(
-        senders, receivers, x.shape[0], edge_valid=edge_valid)
+            plan: Union[AggregationPlan, Sequence[AggregationPlan]] = None
+            ) -> Array:
+    plans = per_layer(plan if plan is not None else edge_plan(
+        senders, receivers, x.shape[0], edge_valid=edge_valid), cfg.n_layers)
     h = x
-    for i in range(cfg.n_layers):
+    for i, pl in enumerate(plans):
         p = params[f"layer{i}"]
         agg = sb.aggregate(pl, None, h, backend=backend)
-        h = mlp_apply(p["mlp"], (1.0 + p["eps"]) * h + agg, act=jax.nn.relu)
+        h = mlp_apply(p["mlp"], (1.0 + p["eps"]) * h[: pl.out_rows] + agg,
+                      act=jax.nn.relu)
         if i < cfg.n_layers - 1:
             h = jax.nn.relu(h)
     return h

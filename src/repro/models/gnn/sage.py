@@ -11,13 +11,13 @@ bandwidth-bound reduction.
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from typing import Optional, Sequence, Union
 
 import jax
 import jax.numpy as jnp
 
 from repro.sparse import backend as sb
-from repro.sparse.plan import AggregationPlan, edge_plan
+from repro.sparse.plan import AggregationPlan, edge_plan, per_layer
 
 Array = jax.Array
 
@@ -53,21 +53,25 @@ def init_params(key, cfg: SAGEConfig):
 def forward(params, cfg: SAGEConfig, x: Array, senders: Array = None,
             receivers: Array = None, edge_valid: Array = None,
             backend: str = "dense",
-            plan: Optional[AggregationPlan] = None) -> Array:
-    pl = plan if plan is not None else edge_plan(
-        senders, receivers, x.shape[0], edge_valid=edge_valid)
-    # in-degree: per-graph layout metadata, not per-layer compute
-    deg = jax.ops.segment_sum(pl.valid.astype(x.dtype), pl.rows,
-                              num_segments=pl.n_rows)
-    inv_deg = (1.0 / jnp.maximum(deg, 1.0))[:, None]
+            plan: Union[AggregationPlan, Sequence[AggregationPlan]] = None
+            ) -> Array:
+    """Logits of every row, or with one plan a layer (each writing only the
+    rows the next reads, ``plan.row_prefix_plan``) of the last plan's."""
+    plans = per_layer(plan if plan is not None else edge_plan(
+        senders, receivers, x.shape[0], edge_valid=edge_valid), cfg.n_layers)
     h = x
-    for i in range(cfg.n_layers):
+    for i, pl in enumerate(plans):
+        if i == 0 or pl is not plans[i - 1]:
+            # in-degree: per-graph layout metadata, not per-layer compute
+            deg = jax.ops.segment_sum(pl.valid.astype(x.dtype), pl.rows,
+                                      num_segments=pl.out_rows)
+            inv_deg = (1.0 / jnp.maximum(deg, 1.0))[:, None]
         p = params[f"layer{i}"]
         # stable names for a profile: the neighbour mean, then the dense part
         with jax.named_scope(f"layer{i}.aggregate"):
             nbr = sb.aggregate(pl, None, h, backend=backend) * inv_deg
         with jax.named_scope(f"layer{i}.dense"):
-            h = (h @ p["w_self"].astype(h.dtype)
+            h = (h[: pl.out_rows] @ p["w_self"].astype(h.dtype)
                  + nbr @ p["w_nbr"].astype(h.dtype) + p["b"].astype(h.dtype))
             if i < cfg.n_layers - 1:
                 h = jax.nn.relu(h)

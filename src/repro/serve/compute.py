@@ -5,7 +5,10 @@ ids, ``-1`` on padding lanes) and ``hop_valid`` — gathers features from the
 resident device store (padding lanes hit the zero ghost row), re-values the
 bucket's static host aggregation plan (``plan_with_values``), runs the
 model forward through the unified backend registry, and returns the seed
-rows (slots ``0..n_seeds-1`` of the breadth-major bucket layout).
+rows (slots ``0..n_seeds-1`` of the breadth-major bucket layout).  A conv
+model runs each layer on a cut of that plan (``layer_plans``) that writes
+only the rows the next layer reads, so its last layer writes just the
+seeds.
 
 All six GNN models serve through here.  The conv family (gcn / sage / gin /
 gat) returns per-seed logits; the geometric family (schnet / dimenet)
@@ -21,7 +24,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Callable, Dict, Optional
+from typing import Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -29,9 +32,10 @@ import numpy as np
 from jax.experimental.layout import Format, Layout
 
 from repro.serve.buckets import BucketStructure, build_bucket_structure
+from repro.sparse import sampler
 from repro.sparse.graph import chunk_width_multiple
-from repro.sparse.plan import make_plan, plan_with_values
-from repro.sparse.stats import record_count
+from repro.sparse.plan import pack_plan, plan_with_values, row_prefix_plan
+from repro.sparse.stats import record_count, record_value
 
 Array = jax.Array
 
@@ -181,25 +185,119 @@ def _build_bucket_plan(key: tuple):
         backends.append(backend)
     if backend == "distributed":
         backends.append("distributed")
-    return make_plan(struct.senders, struct.receivers, struct.n_nodes,
+    return pack_plan(struct.senders, struct.receivers, struct.n_nodes,
                      backends=tuple(backends), width_multiple=width_multiple)
 
 
 _BUCKET_PLANS = StepCache(_build_bucket_plan, maxsize=32)
 
 
+def _plan_key(struct: BucketStructure, backend: str, need_ell: bool):
+    return (struct.n_seeds, struct.fanouts, struct.with_loops, backend,
+            bool(need_ell), chunk_width_multiple())
+
+
 def bucket_plan(struct: BucketStructure, backend: str, need_ell: bool):
     """Host aggregation plan for a bucket's static edge structure, all edges
     valid (per-request validity flows in via ``plan_with_values``)."""
-    return _BUCKET_PLANS.get((struct.n_seeds, struct.fanouts,
-                              struct.with_loops, backend, bool(need_ell),
-                              chunk_width_multiple()))
+    return _BUCKET_PLANS.get(_plan_key(struct, backend, need_ell))
 
 
 def bucket_plan_cache_info() -> dict:
     """Process-wide bucket-plan cache counters (builds/hits/size) — the
     KernelStats registry snapshots these per bench run."""
     return _BUCKET_PLANS.info()
+
+
+def layer_rows(struct: BucketStructure, n_layers: int) -> Tuple[int, ...]:
+    """The rows each layer of an ``n_layers`` conv model writes on the
+    bucket: layer ``l`` only feeds levels ``0..n_layers-1-l`` of the
+    breadth-major layout to the layers after it (every row, once the model
+    is deeper than the tree), so the last writes just the seeds."""
+    levels = np.cumsum([struct.n_seeds]
+                       + sampler.budget(struct.n_seeds, struct.fanouts))
+    return tuple(int(levels[min(n_layers - 1 - i, len(levels) - 1)])
+                 for i in range(n_layers))
+
+
+def _build_layer_plans(key: tuple):
+    n_layers = key[-1]
+    plan = _BUCKET_PLANS.get(key[:-1])
+    struct = build_bucket_structure(*key[:3])
+    n_in, cuts = struct.n_nodes, []
+    # DRHM-permuted rows have no prefix: every layer runs the whole plan
+    rows = ((struct.n_nodes,) * n_layers if plan.has("dist")
+            else layer_rows(struct, n_layers))
+    for r in rows:
+        cuts.append(row_prefix_plan(plan, r, n_in))
+        record_value("plan.layer_rows", r)
+        n_in = r
+    record_count("plan.rows_trimmed", sum(struct.n_nodes - r for r in rows))
+    return tuple(cuts)
+
+
+_LAYER_PLANS = StepCache(_build_layer_plans, maxsize=32)
+
+
+def layer_plans(struct: BucketStructure, backend: str, n_layers: int):
+    """Each layer's ``(plan, edges)`` for an ``n_layers`` conv model on the
+    bucket, cut on the host from the bucket's one plan
+    (``plan.row_prefix_plan``): layer ``l`` writes ``layer_rows[l]`` rows
+    from the previous layer's.  ``edges`` picks the per-edge values each
+    layer keeps (``_valued``)."""
+    return _LAYER_PLANS.get(_plan_key(struct, backend, True) + (n_layers,))
+
+
+def _take_edges(a: Array, edges: np.ndarray) -> Array:
+    """``a[edges]`` for a static sorted ``edges``, as slices of its runs
+    (a bucket keeps a prefix of its hop edges and one of its self loops)."""
+    runs = np.split(edges, np.flatnonzero(np.diff(edges) != 1) + 1)
+    parts = [a[r[0]:r[-1] + 1] for r in runs]
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+
+
+def _valued(cuts, edge_weight: Optional[Array], edge_valid: Array) -> list:
+    """Each layer's plan re-valued with the request's edges (traced)."""
+    return [plan_with_values(
+        pl, edge_weight=(None if edge_weight is None
+                         else _take_edges(edge_weight, edges)),
+        edge_valid=_take_edges(edge_valid, edges)) for pl, edges in cuts]
+
+
+def _conv_forward(arch_id: str, cfg, struct: BucketStructure,
+                  backend: str) -> Callable:
+    """``forward(params, x, node_ids, hop_valid) -> (n_seeds, d_out)`` of a
+    conv-family model on the bucket, each layer on its own cut plan — the
+    one body the single-device step and the cluster's lanes share."""
+    arch = _arch_key(arch_id)
+    if arch == "gcn" and not struct.with_loops:
+        raise ValueError("gcn serving needs with_loops=True structure "
+                         "(A + I normalization)")
+    import importlib
+    m = importlib.import_module(f"repro.models.gnn.{arch}")
+    cuts = layer_plans(struct, backend, cfg.n_layers)
+    n, k = struct.n_nodes, struct.n_seeds
+    senders, receivers = struct.senders, struct.receivers
+
+    def forward(params, x, node_ids, hop_valid):
+        ev = _edge_validity(struct, node_ids, hop_valid)
+        w = None
+        if arch == "gcn":
+            # symmetric normalization on the sampled subgraph, traced:
+            # in-degree over valid edges (self loops included)
+            deg = jax.ops.segment_sum(ev.astype(jnp.float32), receivers,
+                                      num_segments=n)
+            dinv = jax.lax.rsqrt(jnp.maximum(deg, 1.0))
+            w = jnp.take(dinv, senders) * jnp.take(dinv, receivers)
+        return m.forward(params, cfg, x, backend=backend,
+                         plan=_valued(cuts, w, ev))[:k]
+    return forward
+
+
+def _edge_validity(struct: BucketStructure, node_ids, hop_valid):
+    if struct.with_loops:
+        return jnp.concatenate([hop_valid, node_ids >= 0])
+    return hop_valid
 
 
 # ---------------------------------------------------------------------------
@@ -211,80 +309,49 @@ def build_infer_step(arch_id: str, cfg, store: FeatureStore,
                      jit: bool = True) -> Callable:
     """``step(params, node_ids, hop_valid) -> (n_seeds, d_out)`` for one
     bucket, jitted, with ``store`` bound as its first jit argument.  The
-    bucket's structure and plan are closed over.  ``jit=False`` returns the
+    bucket's structure and plans are closed over.  ``jit=False`` returns the
     unbound traced body ``step(store, params, node_ids, hop_valid)``."""
     arch = _arch_key(arch_id)
     n = struct.n_nodes
     k = struct.n_seeds
-    senders = jnp.asarray(struct.senders)
-    receivers = jnp.asarray(struct.receivers)
-    # conv aggregations route scalar per-edge values through `aggregate`,
-    # which on pallas needs the dedup-chunk layout; the geometric family
-    # only `accumulate`s vector messages (pallas falls back to the chunked
-    # schedule there — DESIGN.md §3.3), so COO sections suffice.
-    plan0 = bucket_plan(struct, backend, need_ell=arch in CONV_ARCHS)
-
-    if arch == "gcn" and not struct.with_loops:
-        raise ValueError("gcn serving needs with_loops=True structure "
-                         "(A + I normalization)")
     if arch in CONV_ARCHS and store.x is None:
         raise ValueError(f"{arch} serving needs FeatureStore.x")
     if arch in GEOM_ARCHS and (store.species is None or store.pos is None):
         raise ValueError(f"{arch} serving needs FeatureStore.species/pos")
 
-    def edge_validity(node_ids, hop_valid):
-        if struct.with_loops:
-            return jnp.concatenate([hop_valid, node_ids >= 0])
-        return hop_valid
-
-    if arch == "gcn":
-        from repro.models.gnn import gcn as m
+    if arch in CONV_ARCHS:
+        forward = _conv_forward(arch_id, cfg, struct, backend)
 
         def step(store, params, node_ids, hop_valid):
             with jax.named_scope("serve.gather"):
                 x = jnp.take(store.x, store.row_index(node_ids), axis=0)
-            ev = edge_validity(node_ids, hop_valid)
-            # symmetric normalization on the sampled subgraph, traced:
-            # in-degree over valid edges (self loops included)
-            deg = jax.ops.segment_sum(ev.astype(jnp.float32), receivers,
-                                      num_segments=n)
-            dinv = jax.lax.rsqrt(jnp.maximum(deg, 1.0))
-            w = jnp.take(dinv, senders) * jnp.take(dinv, receivers)
-            pl = plan_with_values(plan0, edge_weight=w, edge_valid=ev)
-            return m.forward(params, cfg, x, backend=backend, plan=pl)[:k]
+            return forward(params, x, node_ids, hop_valid)
 
-    elif arch in ("sage", "gin", "gat"):
-        # unweighted conv family: one shared closure, the model module is
-        # the only thing that differs (validity flows in as plan values)
-        import importlib
-        m = importlib.import_module(f"repro.models.gnn.{arch}")
+        return functools.partial(jax.jit(step), store) if jit else step
 
-        def step(store, params, node_ids, hop_valid):
-            with jax.named_scope("serve.gather"):
-                x = jnp.take(store.x, store.row_index(node_ids), axis=0)
-            pl = plan_with_values(plan0,
-                                  edge_valid=edge_validity(node_ids,
-                                                           hop_valid))
-            return m.forward(params, cfg, x, backend=backend, plan=pl)[:k]
+    # the geometric family only `accumulate`s vector messages (pallas falls
+    # back to the chunked schedule there — DESIGN.md §3.3), so COO
+    # sections suffice
+    plan0 = bucket_plan(struct, backend, need_ell=False)
+    senders = jnp.asarray(struct.senders)
+    receivers = jnp.asarray(struct.receivers)
+    graph_ids = jnp.arange(n, dtype=jnp.int32)
 
-    elif arch == "schnet":
+    if arch == "schnet":
         from repro.models.gnn import schnet as m
-        graph_ids = jnp.arange(n, dtype=jnp.int32)
 
         def step(store, params, node_ids, hop_valid):
             idx = store.row_index(node_ids)
             species = jnp.take(store.species, idx)
             pos = jnp.take(store.pos, idx, axis=0)
-            pl = plan_with_values(plan0,
-                                  edge_valid=edge_validity(node_ids,
-                                                           hop_valid))
+            pl = plan_with_values(plan0, edge_valid=_edge_validity(
+                struct, node_ids, hop_valid))
             e = m.forward(params, cfg, species, pos, graph_ids=graph_ids,
                           n_graphs=n, backend=backend, plan=pl)
             return e[:k, None]
 
     else:  # dimenet
         from repro.models.gnn import dimenet as m
-        graph_ids = jnp.arange(n, dtype=jnp.int32)
         t_in = jnp.asarray(struct.t_in)
         t_out = jnp.asarray(struct.t_out)
 
@@ -292,7 +359,7 @@ def build_infer_step(arch_id: str, cfg, store: FeatureStore,
             idx = store.row_index(node_ids)
             species = jnp.take(store.species, idx)
             pos = jnp.take(store.pos, idx, axis=0)
-            ev = edge_validity(node_ids, hop_valid)
+            ev = _edge_validity(struct, node_ids, hop_valid)
             tv = jnp.take(ev, t_in) & jnp.take(ev, t_out)
             pl = plan_with_values(plan0, edge_valid=ev)
             e = m.forward(params, cfg, species, pos, senders, receivers, ev,
@@ -323,39 +390,7 @@ def _lane_body(arch_id: str, cfg, struct: BucketStructure,
     if arch not in CONV_ARCHS:
         raise ValueError(f"cluster serving covers the conv family "
                          f"{CONV_ARCHS}; {arch!r} is single-device only")
-    if arch == "gcn" and not struct.with_loops:
-        raise ValueError("gcn serving needs with_loops=True structure "
-                         "(A + I normalization)")
-    n = struct.n_nodes
-    k = struct.n_seeds
-    senders = jnp.asarray(struct.senders)
-    receivers = jnp.asarray(struct.receivers)
-    plan0 = bucket_plan(struct, backend, need_ell=True)
-
-    import importlib
-    m = importlib.import_module(f"repro.models.gnn.{arch}")
-
-    def edge_validity(node_ids, hop_valid):
-        if struct.with_loops:
-            return jnp.concatenate([hop_valid, node_ids >= 0])
-        return hop_valid
-
-    if arch == "gcn":
-        def body(params, x, node_ids, hop_valid):
-            ev = edge_validity(node_ids, hop_valid)
-            deg = jax.ops.segment_sum(ev.astype(jnp.float32), receivers,
-                                      num_segments=n)
-            dinv = jax.lax.rsqrt(jnp.maximum(deg, 1.0))
-            w = jnp.take(dinv, senders) * jnp.take(dinv, receivers)
-            pl = plan_with_values(plan0, edge_weight=w, edge_valid=ev)
-            return m.forward(params, cfg, x, backend=backend, plan=pl)[:k]
-    else:
-        def body(params, x, node_ids, hop_valid):
-            pl = plan_with_values(plan0,
-                                  edge_valid=edge_validity(node_ids,
-                                                           hop_valid))
-            return m.forward(params, cfg, x, backend=backend, plan=pl)[:k]
-    return body
+    return _conv_forward(arch_id, cfg, struct, backend)
 
 
 def build_lane_infer_step(arch_id: str, cfg, struct: BucketStructure,

@@ -182,12 +182,12 @@ def _mask_messages(plan: AggregationPlan, messages: Array) -> Array:
 def _dense_aggregate(plan, vals, x):
     pp = jnp.take(x, plan.cols, axis=0)
     pp = pp * _edge_vals(plan, vals, pp.dtype)[:, None]
-    return jax.ops.segment_sum(pp, plan.rows, num_segments=plan.n_rows)
+    return jax.ops.segment_sum(pp, plan.rows, num_segments=plan.out_rows)
 
 
 def _dense_accumulate(plan, messages):
     return jax.ops.segment_sum(_mask_messages(plan, messages), plan.rows,
-                               num_segments=plan.n_rows)
+                               num_segments=plan.out_rows)
 
 
 register_backend(Backend("dense", _dense_aggregate, _dense_accumulate))
@@ -199,14 +199,14 @@ register_backend(Backend("dense", _dense_aggregate, _dense_accumulate))
 
 def _chunked_aggregate(plan, vals, x):
     v = _edge_vals(plan, vals, x.dtype)
-    return core_spgemm.spmm_chunked(plan.rows, plan.cols, v, x, plan.n_rows,
-                                    chunk=plan.chunk)
+    return core_spgemm.spmm_chunked(plan.rows, plan.cols, v, x,
+                                    plan.out_rows, chunk=plan.chunk)
 
 
 def _chunked_accumulate(plan, messages):
     return core_spgemm.segment_sum_chunked(plan.rows,
                                            _mask_messages(plan, messages),
-                                           plan.n_rows, chunk=plan.chunk)
+                                           plan.out_rows, chunk=plan.chunk)
 
 
 register_backend(Backend("chunked", _chunked_aggregate, _chunked_accumulate))
@@ -244,7 +244,7 @@ def _pallas_aggregate(plan, vals, x):
         block_rows=plan.block_rows, n_blocks=plan.n_blocks,
         n_t_blocks=plan.n_t_blocks, group=plan.ell_group,
         d_tile=plan.ell_d_tile)
-    return y[: plan.n_rows]
+    return y[: plan.out_rows]
 
 
 def _pallas_accumulate(plan, messages):
@@ -292,7 +292,7 @@ def _pallas_q8_aggregate(plan, vals, x):
             plan.ell_first, a_q8, a_scale, x.q8, x.scale,
             block_rows=plan.block_rows, n_blocks=plan.n_blocks,
             group=plan.ell_group, d_tile=plan.ell_d_tile)
-        return y[: plan.n_rows]
+        return y[: plan.out_rows]
     # X quantizes per feature tile inside the op (the scales must be computed
     # with the kernel's own d_tile); output returns in x.dtype
     y = gops.spmm_dedup_grad_q8(
@@ -304,7 +304,7 @@ def _pallas_q8_aggregate(plan, vals, x):
         block_rows=plan.block_rows, n_blocks=plan.n_blocks,
         n_t_blocks=plan.n_t_blocks, group=plan.ell_group,
         d_tile=plan.ell_d_tile)
-    return y[: plan.n_rows]
+    return y[: plan.out_rows]
 
 
 register_backend(Backend("pallas_q8", _pallas_q8_aggregate,

@@ -31,7 +31,9 @@ host-built ``make_plan``).
 Conventions (same as everywhere else in the repo): ``rows`` are *receivers*
 (the accumulating side), ``cols`` are *senders*; ``n_rows`` is the padded
 node count **including** the ghost row, i.e. ``x.shape[0]``; padding edges
-carry ``valid == False`` and contribute nothing.
+carry ``valid == False`` and contribute nothing.  A plan writes ``n_rows``
+output rows unless ``row_prefix_plan`` cut it to the first ``out_rows``
+(a layer whose next layer reads only a prefix of the rows).
 """
 from __future__ import annotations
 
@@ -67,6 +69,7 @@ class AggregationPlan:
     rows_per_shard: int = 0
     edges_per_shard: int = 0
     mesh: Optional[object] = None    # jax Mesh (hashable) for `distributed`
+    n_out_rows: Optional[int] = None  # output rows (None → n_rows)
 
     # --- COO section (always present; may hold tracers) ---
     rows: Optional[Array] = None       # (E_pad,) int32 — receivers
@@ -118,6 +121,11 @@ class AggregationPlan:
                 f" ...)) — inline edge_plan() covers only dense/chunked")
 
     @property
+    def out_rows(self) -> int:
+        """Rows the plan writes: ``n_rows``, or a row prefix of them."""
+        return self.n_rows if self.n_out_rows is None else self.n_out_rows
+
+    @property
     def dist_n_pad(self) -> int:
         return self.n_shards * self.rows_per_shard
 
@@ -134,7 +142,8 @@ _LEAF_FIELDS = (
 )
 _AUX_FIELDS = ("n_rows", "chunk", "block_rows", "n_blocks", "n_t_blocks",
                "ell_group", "ell_d_tile",
-               "n_shards", "rows_per_shard", "edges_per_shard", "mesh")
+               "n_shards", "rows_per_shard", "edges_per_shard", "mesh",
+               "n_out_rows")
 
 
 def _plan_flatten(p: AggregationPlan):
@@ -178,6 +187,18 @@ def edge_plan(senders: Array, receivers: Array, n_rows: int,
 
 def make_plan(senders: np.ndarray, receivers: np.ndarray, n_rows: int,
               edge_weight: Optional[np.ndarray] = None,
+              edge_valid: Optional[np.ndarray] = None,
+              **kwargs) -> AggregationPlan:
+    """``pack_plan`` with every table on the device."""
+    plan = pack_plan(senders, receivers, n_rows, edge_weight, edge_valid,
+                     **kwargs)
+    return dataclasses.replace(plan, **{
+        f: jnp.asarray(getattr(plan, f)) for f in _LEAF_FIELDS
+        if getattr(plan, f) is not None})
+
+
+def pack_plan(senders: np.ndarray, receivers: np.ndarray, n_rows: int,
+              edge_weight: Optional[np.ndarray] = None,
               edge_valid: Optional[np.ndarray] = None, *,
               backends: Sequence[str] = ("dense", "chunked"),
               chunk: int = 8192, block_rows: int = 8, width_cap: int = 128,
@@ -185,7 +206,9 @@ def make_plan(senders: np.ndarray, receivers: np.ndarray, n_rows: int,
               d_tile: Optional[int] = None,
               mesh=None, gamma: int = 0x9E3779B1,
               edge_pad_multiple: int = 8) -> AggregationPlan:
-    """Host-side plan: precompute every layout in ``backends`` once.
+    """Host-side plan: precompute every layout in ``backends`` once, its
+    tables left in host memory (a jitted step that closes over them embeds
+    them as constants; ``row_prefix_plan`` cuts them with no device op).
 
     Only valid edges enter the pallas/distributed layouts; invalid (padding)
     edges get an out-of-bounds scatter slot, so traced per-edge values on
@@ -205,9 +228,8 @@ def make_plan(senders: np.ndarray, receivers: np.ndarray, n_rows: int,
          else np.asarray(edge_weight, np.float32))
     base = np.where(valid, w, 0.0).astype(np.float32)
     vidx = np.nonzero(valid)[0]
-    kw = dict(n_rows=int(n_rows), chunk=chunk,
-              rows=jnp.asarray(r), cols=jnp.asarray(s),
-              valid=jnp.asarray(valid), base_vals=jnp.asarray(base))
+    kw = dict(n_rows=int(n_rows), chunk=chunk, rows=r, cols=s, valid=valid,
+              base_vals=base)
 
     if "pallas" in backends or "pallas_q8" in backends:
         from repro.kernels.gustavson_spmm.gustavson_spmm import (
@@ -243,18 +265,12 @@ def make_plan(senders: np.ndarray, receivers: np.ndarray, n_rows: int,
         t_slots[vidx] = tr.slots
         kw.update(block_rows=block_rows, n_blocks=fwd.n_blocks,
                   n_t_blocks=tr.n_blocks, ell_group=group, ell_d_tile=d_tile,
-                  ell_u_cols=jnp.asarray(fwd.u_cols),
-                  ell_remaining=jnp.asarray(fwd.remaining),
-                  ell_out_block=jnp.asarray(fwd.out_block),
-                  ell_first=jnp.asarray(fwd.first),
-                  ell_a=jnp.asarray(fwd.a),
-                  ell_slots=jnp.asarray(slots),
-                  ell_t_u_cols=jnp.asarray(tr.u_cols),
-                  ell_t_remaining=jnp.asarray(tr.remaining),
-                  ell_t_out_block=jnp.asarray(tr.out_block),
-                  ell_t_first=jnp.asarray(tr.first),
-                  ell_t_a=jnp.asarray(tr.a),
-                  ell_t_slots=jnp.asarray(t_slots))
+                  ell_u_cols=fwd.u_cols, ell_remaining=fwd.remaining,
+                  ell_out_block=fwd.out_block, ell_first=fwd.first,
+                  ell_a=fwd.a, ell_slots=slots,
+                  ell_t_u_cols=tr.u_cols, ell_t_remaining=tr.remaining,
+                  ell_t_out_block=tr.out_block, ell_t_first=tr.first,
+                  ell_t_a=tr.a, ell_t_slots=t_slots)
         if "pallas_q8" in backends:
             # bake the int8 tiles for the default-values path; traced edge
             # values re-quantize in-jit (plan_with_values / the backend)
@@ -277,12 +293,10 @@ def make_plan(senders: np.ndarray, receivers: np.ndarray, n_rows: int,
         kw.update(mesh=mesh, n_shards=dp.n_shards,
                   rows_per_shard=dp.rows_per_shard,
                   edges_per_shard=dp.edges_per_shard,
-                  dist_rows_local=jnp.asarray(dp.rows_local),
-                  dist_cols_perm=jnp.asarray(dp.cols_perm),
-                  dist_vals=jnp.asarray(dp.vals),
-                  dist_slots=jnp.asarray(slots),
-                  dist_perm=jnp.asarray(dp.perm.astype(np.int32)),
-                  dist_inv_perm=jnp.asarray(dp.inv_perm.astype(np.int32)))
+                  dist_rows_local=dp.rows_local, dist_cols_perm=dp.cols_perm,
+                  dist_vals=dp.vals, dist_slots=slots,
+                  dist_perm=dp.perm.astype(np.int32),
+                  dist_inv_perm=dp.inv_perm.astype(np.int32))
 
     return AggregationPlan(**kw)
 
@@ -328,6 +342,69 @@ def plan_with_values(plan: AggregationPlan,
         flat = jnp.zeros((plan.dist_rows_local.shape[0],), jnp.float32)
         kw["dist_vals"] = flat.at[plan.dist_slots].set(base, mode="drop")
     return dataclasses.replace(plan, **kw)
+
+
+def per_layer(plan, n_layers: int) -> list:
+    """A model's plan for each of its ``n_layers`` layers: a sequence (one
+    plan a layer, as the serving step cuts them) as given, a single plan
+    for every layer."""
+    if isinstance(plan, (list, tuple)):
+        if len(plan) != n_layers:
+            raise ValueError(f"{len(plan)} plans for {n_layers} layers")
+        return list(plan)
+    return [plan] * n_layers
+
+
+def row_prefix_plan(plan: AggregationPlan, out_rows: int, in_rows: int):
+    """Rows ``[0, out_rows)`` of a host plan's matrix, read from rows
+    ``[0, in_rows)`` of ``x``: ``(plan, edges)``, where ``edges`` indexes
+    the kept edges (those into the prefix) in the plan's edge order, for
+    slicing per-edge values before ``plan_with_values``.
+
+    Nothing is re-packed and no device op runs: the chunk layout orders
+    chunks by output block, so the prefix's chunks are the first ``K``;
+    the cut keeps them, the kept edges' scatter slots (all below ``K``'s
+    tiles) and coefficient tiles holding exactly the kept edges.  The
+    transpose layout is cut the other way round (``in_rows`` outputs read
+    from ``out_rows``).  An operand that only rows past a prefix read, in
+    the block that straddles it, points at row 0: its coefficients there
+    are zero, and the kernel never reads a row ``x`` lacks.  The DRHM
+    section permutes rows and has no prefix to cut.
+    """
+    rows, cols = np.asarray(plan.rows), np.asarray(plan.cols)
+    if out_rows >= plan.out_rows and in_rows >= plan.n_rows:
+        return plan, np.arange(rows.shape[0])
+    if plan.has("dist"):
+        raise BackendPlanError("a distributed plan's rows are DRHM-permuted;"
+                               " it has no row prefix to cut")
+    edges = np.flatnonzero(rows < out_rows)
+    if edges.size and int(cols[edges].max()) >= in_rows:
+        raise ValueError(f"rows below {out_rows} read rows past {in_rows}")
+    base = np.asarray(plan.base_vals)[edges]
+    kw = dict(n_rows=int(in_rows), n_out_rows=int(out_rows),
+              rows=rows[edges], cols=cols[edges],
+              valid=np.asarray(plan.valid)[edges], base_vals=base,
+              ell_a_q8=None, ell_a_scale=None)   # re-quantized in the step
+    if plan.ell_u_cols is not None:
+        br = plan.block_rows
+        for pre, blocks, n_out, n_in in (("ell_", "n_blocks", out_rows,
+                                          in_rows),
+                                         ("ell_t_", "n_t_blocks", in_rows,
+                                          out_rows)):
+            n_blocks = -(-int(n_out) // br)
+            k = int(np.searchsorted(np.asarray(getattr(plan,
+                                                       pre + "out_block")),
+                                    n_blocks))
+            u_cols = np.asarray(getattr(plan, pre + "u_cols"))[:k]
+            slots = np.asarray(getattr(plan, pre + "slots"))[edges]
+            a = np.zeros((k * br, u_cols.shape[1]), np.float32)
+            inside = slots < a.size    # an invalid edge's slot lies past
+            np.add.at(a.reshape(-1), slots[inside], base[inside])
+            kw.update({blocks: n_blocks, pre + "a": a, pre + "slots": slots,
+                       pre + "u_cols": np.where(u_cols < n_in, u_cols, 0)})
+            for f in ("remaining", "out_block", "first"):
+                kw[pre + f] = np.asarray(getattr(plan, pre + f))[:k]
+    return dataclasses.replace(plan, **kw), edges
 
 
 # ---------------------------------------------------------------------------

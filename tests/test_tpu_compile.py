@@ -188,6 +188,15 @@ def _lower_fused_gat_step(one_chip):
         server.close()
 
 
+def _gustavson_calls(text: str) -> list:
+    """``(layer scope, output rows)`` of each Gustavson kernel call in a
+    compiled step's text, in program order."""
+    calls = re.findall(r"= f32\[(\d+),\d+\]\S* custom-call\(.*?"
+                       r"custom_call_target=\"tpu_custom_call\".*?"
+                       r"op_name=\"[^\"/]*/(layer\d+)\.aggregate/", text)
+    return [(layer, int(rows)) for rows, layer in calls]
+
+
 def test_fused_gat_serving_step_compiles(one_chip, compile_for_tpu):
     text = _lower_fused_gat_step(one_chip).compile().as_text()
     kernels = re.findall(r"custom_call_target=\"tpu_custom_call\".*?"
@@ -198,6 +207,45 @@ def test_fused_gat_serving_step_compiles(one_chip, compile_for_tpu):
     assert sorted({k.split("/")[1] for k in gustavson}) == [
         "layer0.aggregate", "layer1.aggregate", "layer2.aggregate"]
     assert len(gustavson) == 12
+    # each layer writes only the rows the next reads (16-row blocks):
+    # levels 0-2 of the 17,776-row bucket, then 0-1, then the seeds
+    assert sorted(_gustavson_calls(text)) == (
+        [("layer0", 1776)] * 4 + [("layer1", 176)] * 4
+        + [("layer2", 16)] * 4)
+
+
+def test_fused_products_sage_step_aggregates_the_rows_it_answers(
+        one_chip, compile_for_tpu):
+    """The 16-seed bucket program of the products GraphSAGE (100 -> 256 ->
+    256 -> 47, fanouts (15, 10, 5), 14,656 rows): one Gustavson call a
+    layer, each over the levels the next layer reads."""
+    from repro.data import synthetic as syn
+    from repro.models.gnn import sage
+    from repro.serve import GNNServer
+    from repro.serve.compute import FeatureStore
+    from repro.sparse.graph import coo_to_csr
+    cfg = sage.SAGEConfig(n_layers=3, d_in=100, d_hidden=256, n_classes=47)
+    params = sage.init_params(jax.random.key(0), cfg)
+    s, r = syn.powerlaw_graph(300, 3000, seed=0)
+    indptr, indices = coo_to_csr(s, r, 300)[:2]
+    x = np.random.default_rng(0).normal(size=(300, 100)).astype(np.float32)
+    server = GNNServer("sage", cfg, params, indptr, indices,
+                       FeatureStore.build(300, x=x), fanouts=(15, 10, 5),
+                       backend="pallas", sampler="device",
+                       max_batch_seeds=16)
+    try:
+        step = server.steps.get((16,))
+        bound = jax.tree.map(lambda a: _spec(a, one_chip),
+                             (step.args, params))
+        seeds = jax.ShapeDtypeStruct((16,), jnp.int32, sharding=one_chip)
+        keys = jax.ShapeDtypeStruct((16,), jnp.uint32, sharding=one_chip)
+        live = jax.ShapeDtypeStruct((16,), jnp.bool_, sharding=one_chip)
+        text = step.func.lower(*bound[0], bound[1], seeds, keys, keys,
+                               live).compile().as_text()
+    finally:
+        server.close()
+    assert sorted(_gustavson_calls(text)) == [
+        ("layer0", 2656), ("layer1", 256), ("layer2", 16)]
 
 
 def test_fused_gat_step_carries_the_stage_scopes(one_chip, compile_for_tpu):

@@ -55,8 +55,6 @@ def main():
         print(f"{name},{pp},{nnz},{analytic:.1f},{measured:.1f},"
               f"{match},{paper},{us_a:.0f},{us_s:.0f}")
     if mismatches:
-        # RuntimeError, not SystemExit: benchmarks/run.py isolates module
-        # failures with `except Exception` and must still write artifacts
         raise RuntimeError(f"{mismatches} measured/analytic mismatches")
 
 

@@ -17,26 +17,6 @@ from repro.neurasim import datasets, machine, model
 PAPER_GNN_SPEEDUP = {"EnGN": 1.29, "GROW": 1.58, "HyGCN": 1.69,
                      "FlowGNN": 1.30}
 
-def backend_rows():
-    """Measured GCN aggregation (d=16, the paper's hidden dim) per backend,
-    identical Cora graph for all executors — selected by config string
-    through the unified registry (sweep loop: benchmarks.backend_sweep)."""
-    import jax.numpy as jnp
-    from benchmarks.backend_sweep import sweep_aggregate
-    from repro.sparse import backend as sparse_backend
-    from repro.sparse.graph import sym_norm_weights
-    from repro.sparse.plan import make_plan
-
-    s, r, x, y, c = cora_like()
-    n = 2708
-    s2, r2, w = sym_norm_weights(s, r, n)
-    plan = make_plan(s2, r2, n + 1, edge_weight=w,
-                     backends=sparse_backend.ALL_BACKENDS, chunk=4096)
-    rng = np.random.default_rng(0)
-    h = jnp.asarray(rng.normal(size=(n + 1, 16)).astype(np.float32))
-    return [(f"cora_aggregation_{name}", us, "d=16")
-            for name, us, _ in sweep_aggregate(plan, h)]
-
 
 def run():
     cfg = machine.TILE16
@@ -62,8 +42,6 @@ def main():
         print(f"gcn_{name},{us:.0f},gops={gops:.2f};bound={bound}")
     for acc, sp in PAPER_GNN_SPEEDUP.items():
         print(f"paper_speedup_vs_{acc},0,claimed={sp}x")
-    for name, us, extra in backend_rows():
-        print(f"{name},{us:.0f},{extra}")
 
 
 if __name__ == "__main__":

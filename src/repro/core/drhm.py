@@ -160,8 +160,8 @@ def _kstats():
 def bin_balance_snapshot(assignment, n_bins: int) -> dict:
     """Host-side bin-load summary (+ a NeuraScope ``drhm.imbalance`` sample).
 
-    The observability companion to ``imbalance``: benches and the cluster
-    router call it on concrete assignments to leave an auditable balance
+    The observability companion to ``imbalance``: the cluster router
+    calls it on concrete assignments to leave an auditable balance
     trail per reseed epoch.
     """
     c = np.bincount(np.asarray(assignment, np.int64), minlength=int(n_bins))

@@ -280,8 +280,7 @@ def main():
     # control plane (DESIGN.md §13) — cluster path only
     ap.add_argument("--telemetry-jsonl", default=None, metavar="PATH",
                     help="append per-lane telemetry samples/events as JSON "
-                         "lines (the flight recorder the chaos benchmark "
-                         "mines)")
+                         "lines (the flight recorder neurascope renders)")
     ap.add_argument("--deadline-ms", type=float, default=None,
                     help="per-request deadline; queued requests past it "
                          "fail typed (DeadlineExceeded) instead of serving "

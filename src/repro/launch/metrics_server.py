@@ -9,7 +9,7 @@ Opt-in surface for the online metrics plane: pass
 * ``GET /healthz``  — ``ok\\n``, for liveness probes and CI smokes.
 
 ``port=0`` binds an ephemeral port; the real one is ``server.port`` (and
-is what the benches use so parallel runs never collide).  Stdlib only —
+is what the tests use so parallel runs never collide).  Stdlib only —
 no new dependencies.
 
 ``python -m repro.launch.metrics_server --smoke`` is the self-test CI

@@ -1,16 +1,15 @@
 """NeuraScope — the paper-style performance visualizer over the serving
-flight recorder and the committed bench trajectory (DESIGN.md §14–15).
+flight recorder (DESIGN.md §14–15).
 
-  # render a self-contained HTML report from a chaos-bench flight recorder
+  # render a self-contained HTML report from a flight recorder (any
+  # ClusterServer/GNNServer run with telemetry_jsonl=... and tracing=True)
   PYTHONPATH=src python -m repro.launch.neurascope \
-      artifacts/BENCH_chaos_flight.jsonl \
-      --bench BENCH_serving.json BENCH_cluster.json \
-      --out artifacts/neurascope.html
+      artifacts/flight.jsonl --out artifacts/neurascope.html
 
-  # CI smoke: terminal summary + schema/span-tree validation (exit != 0 on
-  # a malformed recorder)
+  # terminal summary + schema/span-tree validation (exit != 0 on a
+  # malformed recorder)
   PYTHONPATH=src python -m repro.launch.neurascope \
-      artifacts/BENCH_chaos_flight.jsonl --summary --check
+      artifacts/flight.jsonl --summary --check
 
   # live dashboard: auto-refreshing terminal panels (per-lane heat, SLO
   # burn rate, kernel-counter sparklines) off a /metrics endpoint or a
@@ -18,23 +17,17 @@ flight recorder and the committed bench trajectory (DESIGN.md §14–15).
   PYTHONPATH=src python -m repro.launch.neurascope \
       http://127.0.0.1:9100/metrics --live
   PYTHONPATH=src python -m repro.launch.neurascope \
-      artifacts/BENCH_chaos_flight.jsonl --live --interval 0.5
+      artifacts/flight.jsonl --live --interval 0.5
 
-Three data sources, one report:
-
-* the **flight recorder** JSONL (``TelemetryHub`` + ``Tracer`` records,
-  one versioned schema) — span waterfalls for the slowest/p99 request
-  traces, per-lane queue-depth/inflight timelines, the event log;
-* the **kernel-stats snapshot** embedded in ``BENCH_*.json`` — hash-pad
-  occupancy/collision histograms, dedup-chunk shape, DRHM balance;
-* the **trajectory** history in ``BENCH_*.json`` — sparklines of every
-  gated metric across committed runs.
+The **flight recorder** JSONL (``TelemetryHub`` + ``Tracer`` records, one
+versioned schema) gives span waterfalls for the slowest/p99 request
+traces, per-lane queue-depth/inflight timelines and the event log.
 
 The HTML is fully self-contained (inline SVG + CSS, zero external assets,
-no JS) so it can be archived as a CI artifact and opened anywhere.
-``--check`` runs ``tracing.verify_traces`` plus schema-version validation
-over every record — the same verifier the span-completeness property tests
-pin — and fails nonzero so CI can gate on a healthy recorder.
+no JS) so it can be archived and opened anywhere.  ``--check`` runs
+``tracing.verify_traces`` plus schema-version validation over every
+record — the same verifier the span-completeness property tests pin — and
+fails nonzero on an unhealthy recorder.
 """
 from __future__ import annotations
 
@@ -105,17 +98,6 @@ def load_flight(path: str) -> Tuple[Dict[str, list], dict]:
                 else:
                     meta["other_kinds"] += 1
     return recs, meta
-
-
-def load_benches(paths: List[str]) -> List[Tuple[str, dict]]:
-    out = []
-    for p in paths:
-        try:
-            with open(p) as f:
-                out.append((os.path.basename(p), json.load(f)))
-        except (OSError, ValueError) as e:
-            print(f"neurascope: skipping {p}: {e}", file=sys.stderr)
-    return out
 
 
 # ---------------------------------------------------------------------------
@@ -237,50 +219,6 @@ def svg_lane_timeline(samples: List[dict], field: str, width: int = 860,
     return _svg(width, height, "".join(parts))
 
 
-def svg_histogram(values: List[float], width: int = 400, height: int = 110,
-                  bins: int = 16) -> str:
-    if not values:
-        return "<p>(no samples)</p>"
-    lo, hi = min(values), max(values)
-    span = max(hi - lo, 1e-12)
-    counts = [0] * bins
-    for v in values:
-        counts[min(int((v - lo) / span * bins), bins - 1)] += 1
-    cmax = max(counts)
-    pad_b = 16
-    bw = (width - 8) / bins
-    ph = height - pad_b - 6
-    parts = []
-    for i, c in enumerate(counts):
-        bh = ph * c / max(cmax, 1)
-        parts.append(
-            f'<rect x="{4 + i * bw:.1f}" y="{6 + ph - bh:.1f}" '
-            f'width="{bw - 1:.1f}" height="{bh:.1f}" fill="#8ab4f8">'
-            f'<title>[{lo + span * i / bins:.3g}, '
-            f'{lo + span * (i + 1) / bins:.3g}): {c}</title></rect>')
-    parts.append(f'<text x="4" y="{height - 4}" font-size="10" '
-                 f'fill="#777">{lo:.3g}</text>')
-    parts.append(f'<text x="{width - 60}" y="{height - 4}" font-size="10" '
-                 f'fill="#777">{hi:.3g}</text>')
-    return _svg(width, height, "".join(parts))
-
-
-def svg_sparkline(values: List[float], width: int = 180,
-                  height: int = 36) -> str:
-    if len(values) < 2:
-        return f'<span style="color:#777">{values and values[0]}</span>'
-    lo, hi = min(values), max(values)
-    span = max(hi - lo, 1e-12)
-    n = len(values)
-    poly = " ".join(
-        f"{4 + (width - 8) * i / (n - 1):.1f},"
-        f"{4 + (height - 8) * (1 - (v - lo) / span):.1f}"
-        for i, v in enumerate(values))
-    return _svg(width, height,
-                f'<polyline points="{poly}" fill="none" stroke="#4285f4" '
-                f'stroke-width="1.5"/>')
-
-
 # ---------------------------------------------------------------------------
 # Report assembly
 # ---------------------------------------------------------------------------
@@ -297,8 +235,7 @@ def _legend() -> str:
     return f'<div class="legend">{chips}</div>'
 
 
-def render_html(recs: Dict[str, list], meta: dict,
-                benches: List[Tuple[str, dict]]) -> str:
+def render_html(recs: Dict[str, list], meta: dict) -> str:
     parts = [
         "<!doctype html><html><head><meta charset='utf-8'>"
         "<title>NeuraScope</title><style>"
@@ -312,7 +249,6 @@ def render_html(recs: Dict[str, list], meta: dict,
         ".chip{display:inline-block;margin-right:10px;font-size:11px}"
         ".sw{display:inline-block;width:10px;height:10px;margin-right:3px;"
         "border-radius:2px}"
-        ".grid{display:flex;flex-wrap:wrap;gap:16px}"
         ".cell{font-size:11px;color:#555}"
         "</style></head><body><h1>NeuraScope</h1>",
         f"<p class='cell'>flight recorder: {_esc(', '.join(meta['files']))}"
@@ -358,50 +294,6 @@ def render_html(recs: Dict[str, list], meta: dict,
             f"Control-plane events ({len(recs['event'])})",
             f"<table><tr><th>t (s)</th><th>event</th><th>fields</th></tr>"
             f"{rows}</table>"))
-
-    # --- kernel stats + trajectory from bench JSONs --------------------------
-    for name, data in benches:
-        ks = data.get("kernel_stats")
-        if isinstance(ks, dict) and (ks.get("counters")
-                                     or ks.get("series")):
-            body = []
-            if ks.get("counters"):
-                rows = "".join(
-                    f"<tr><td>{_esc(k)}</td><td>{v}</td></tr>"
-                    for k, v in sorted(ks["counters"].items()))
-                body.append(f"<table><tr><th>counter</th><th>n</th></tr>"
-                            f"{rows}</table>")
-            hists = []
-            for k, s in sorted((ks.get("series") or {}).items()):
-                sample = s.get("sample") or []
-                hists.append(
-                    f"<div><div class='cell'>{_esc(k)} "
-                    f"(n={s.get('n')}, mean={s.get('mean', 0):.3g}, "
-                    f"max={s.get('max', 0):.3g})</div>"
-                    f"{svg_histogram([float(v) for v in sample])}</div>")
-            if hists:
-                body.append(f"<div class='grid'>{''.join(hists)}</div>")
-            parts.append(_section(f"Compute-plane counters — {name}",
-                                  "".join(body)))
-        traj = data.get("trajectory")
-        if isinstance(traj, list) and len(traj) >= 2:
-            series: Dict[str, List[float]] = {}
-            for snap in traj:
-                for cell, metrics in (snap.get("metrics") or {}).items():
-                    for mk, mv in metrics.items():
-                        if isinstance(mv, bool) or not isinstance(
-                                mv, (int, float)):
-                            continue
-                        series.setdefault(f"{cell} · {mk}",
-                                          []).append(float(mv))
-            cells = "".join(
-                f"<div><div class='cell'>{_esc(k)} "
-                f"(latest {v[-1]:.3g})</div>{svg_sparkline(v)}</div>"
-                for k, v in sorted(series.items()) if len(v) >= 2)
-            if cells:
-                parts.append(_section(
-                    f"Trajectory — {name} ({len(traj)} snapshots)",
-                    f"<div class='grid'>{cells}</div>"))
 
     parts.append("</body></html>")
     return "".join(parts)
@@ -607,7 +499,8 @@ def render_frame(panels: dict, history: Dict[str, List[float]],
 
 def live(source: str, *, interval: float, frames: int) -> int:
     """Auto-refreshing dashboard: scrape a /metrics URL or tail a JSONL.
-    ``frames=0`` runs until interrupted; a finite count is the CI mode."""
+    ``frames=0`` runs until interrupted; a finite count renders that many
+    frames and returns."""
     import time as _time
     is_url = source.startswith("http://") or source.startswith("https://")
     history: Dict[str, List[float]] = {}
@@ -636,12 +529,9 @@ def live(source: str, *, interval: float, frames: int) -> int:
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
-        description="NeuraScope: flight-recorder + trajectory visualizer")
+        description="NeuraScope: flight-recorder visualizer")
     ap.add_argument("flight", help="telemetry/tracing JSONL flight recorder "
                                    "(or, with --live, a /metrics URL)")
-    ap.add_argument("--bench", nargs="*", default=None, metavar="JSON",
-                    help="BENCH_*.json files for kernel stats + trajectory "
-                         "(default: any BENCH_*.json in the cwd)")
     ap.add_argument("--out", default=DEFAULT_OUT,
                     help=f"HTML report path (default {DEFAULT_OUT})")
     ap.add_argument("--summary", action="store_true",
@@ -656,7 +546,7 @@ def main(argv=None) -> int:
                     help="--live refresh period in seconds (default 1.0)")
     ap.add_argument("--frames", type=int, default=0,
                     help="--live frame budget; 0 = run until interrupted "
-                         "(finite counts are the CI smoke mode)")
+                         "(a finite count renders that many frames and exits)")
     args = ap.parse_args(argv)
 
     if args.live:
@@ -675,12 +565,7 @@ def main(argv=None) -> int:
     if args.summary or args.check:
         return rc
 
-    bench_paths = args.bench
-    if bench_paths is None:
-        bench_paths = sorted(
-            p for p in os.listdir(".")
-            if p.startswith("BENCH_") and p.endswith(".json"))
-    doc = render_html(recs, meta, load_benches(bench_paths))
+    doc = render_html(recs, meta)
     out_dir = os.path.dirname(args.out)
     if out_dir:
         os.makedirs(out_dir, exist_ok=True)

@@ -20,8 +20,8 @@ Planes over the engines PRs 1–3 built:
                    Prometheus-style exposition) and ``slo.SLOEngine``
                    (per-class burn-rate tracking feeding the shed arm:
                    best_effort drops before batch, interactive never);
-* measurement    — ``benchmarks/serving_bench.py`` → ``BENCH_serving.json``,
-                   ``benchmarks/cluster_bench.py`` → ``BENCH_cluster.json``.
+* measurement    — ``benchmarks/chip/run.py`` times ``GNNServer`` on the
+                   TPU (``BENCHMARK.json``; results in ``PERF.md``).
 
 Correctness anchors: batched-bucketed serving is parity-checked (≤1e-5)
 against offline one-request-at-a-time inference on the same sampled trees;

@@ -96,7 +96,7 @@ class ChaosInjector:
         self.p_step_fault = float(p_step_fault)
         self.sampler_fault_rids = set(int(r) for r in sampler_fault_rids)
         self.p_sampler_fault = float(p_sampler_fault)
-        # what actually fired, for tests and the chaos benchmark record
+        # what actually fired, for tests and the flight recorder
         self.injected: Dict[str, int] = {"kill": 0, "stall": 0,
                                          "step": 0, "sampler": 0}
 
@@ -148,12 +148,6 @@ class ChaosInjector:
             with self._lock:
                 self.injected["sampler"] += 1
             raise InjectedSamplerFault(req.rid)
-
-    def triggered_wall_times(self) -> Dict[int, float]:
-        """Fault index → wall time (injector clock) the fault first fired —
-        the chaos benchmark's t=0 for detection/recovery measurements."""
-        with self._lock:
-            return dict(self._triggered_at)
 
     def info(self) -> dict:
         with self._lock:

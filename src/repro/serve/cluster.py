@@ -134,8 +134,8 @@ class DRHMRouter:
         self._plan = drhm.plan_request_routing(self._base_bins, self.n_lanes,
                                                self.seed, 0)
         self.n_bins = self._plan.n_pad        # padded to a lane multiple
-        # per-epoch routed counts — the utilization-spread record the bench
-        # reports before/after a reseed
+        # per-epoch routed counts — the utilization spread before/after a
+        # reseed
         self.epoch_counts: List[np.ndarray] = [np.zeros(n_lanes, np.int64)]
         # queue depths at the last reseed: old-map backlog that a new γ
         # cannot fix (those requests drain on the old map) — subtracted
@@ -740,7 +740,7 @@ class ClusterServer:
     def _slo_tick(self, sample: dict):
         """Monitor-tick hook: advance the burn-rate engine; every shed-set
         transition becomes a ``shed_class`` telemetry event (so the flight
-        recorder and the chaos bench see the precedence order)."""
+        recorder sees the precedence order)."""
         for ev in self.slo.tick():
             self.telemetry.event("shed_class", cls=ev["cls"], on=ev["on"],
                                  burn_fast=round(ev["burn_fast"], 4),

@@ -43,7 +43,7 @@ from repro.sparse.delta import DeltaGraphState, chunks_match
 
 @dataclasses.dataclass
 class SwapReport:
-    """One hot-swap, end to end — the bench's ``swap_blackout_ms`` source."""
+    """One hot-swap, end to end."""
 
     step: int                  # checkpoint step that was installed
     old_version: int

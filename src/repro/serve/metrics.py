@@ -26,12 +26,10 @@ Three instrument families, one registry:
 ``MetricsRegistry.render()`` emits the Prometheus/OpenMetrics text format
 (``_bucket{le=...}`` + ``_sum`` + ``_count``, ``# TYPE``/``# HELP``,
 ``# {trace_id=...}`` exemplars); ``parse_exposition`` round-trips it for
-tests, the scrape-vs-summary bench gate, and the ``--live`` dashboard.
+tests (the scrape-vs-summary check) and the ``--live`` dashboard.
 
 Hot-path budget: one ``is None`` test at each call site when metrics are
-off (the chaos convention), one small lock + O(1) array math when on —
-the serving benches gate the end-to-end cost at ≤5% next to the tracing
-overhead gate.
+off (the chaos convention), one small lock + O(1) array math when on.
 """
 from __future__ import annotations
 
@@ -442,7 +440,7 @@ def parse_exposition(text: str) -> Dict[str, dict]:
 
 def histogram_counts_from_samples(samples, match: Dict[str, str]) -> List[int]:
     """Rebuild per-bucket (non-cumulative) counts for the histogram series
-    whose labels are a superset of ``match`` — what the bench and the live
+    whose labels are a superset of ``match`` — what the tests and the live
     dashboard use to read a p99 off a scraped exposition."""
     by_le: Dict[float, float] = {}
     for name, labels, value, _ex in samples:

@@ -26,8 +26,8 @@ Three kinds of records, all cheap on the hot path:
   machine lives there).
 
 With ``jsonl_path`` set, every event and sample is also appended as one
-JSON line — the machine-readable flight recorder the chaos benchmark mines
-for recovery time and p99 spike, and ``launch.neurascope`` renders.  Every
+JSON line — the machine-readable flight recorder an operator mines for
+recovery time and p99 spike, and ``launch.neurascope`` renders.  Every
 record carries ``schema_version`` (shared with the tracing records that
 flush through the same writer) and the file is size-bounded: past
 ``jsonl_max_bytes`` the generations shift (``<path>.k`` → ``<path>.k+1``,
@@ -55,8 +55,8 @@ COUNTERS = ("submitted", "served", "failed", "shed", "timeouts", "retries",
 def percentiles_ms(seconds) -> Dict[str, float]:
     """THE p50/p95/p99 definition — latencies in seconds, linear-interpolated
     ``np.percentile``, reported in milliseconds (0.0 on empty).  One home,
-    shared by the hub, ``GNNServer.stats()``, and both serving benches, so
-    a percentile in any BENCH record means exactly one thing."""
+    shared by the hub, ``GNNServer.stats()`` and the SLO engine, so a
+    percentile in any stats record means exactly one thing."""
     arr = np.asarray(seconds, np.float64)
     if arr.size == 0:
         return {"p50_ms": 0.0, "p95_ms": 0.0, "p99_ms": 0.0}

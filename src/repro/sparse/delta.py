@@ -20,8 +20,8 @@ the plan layer packs **incrementally**:
   vectorized numpy — no python loop over blocks.  Clean blocks reuse their
   cached operand tables.
 
-Parity contract (property-tested in ``tests/test_delta.py`` and gated by
-``benchmarks/cluster_bench.py --mutation``): after any interleaving of
+Parity contract (property-tested in ``tests/test_delta.py``, and under
+live hot-swaps in ``tests/test_live_mutation.py``): after any interleaving of
 inserts/deletes + flushes, ``plan()`` is *structurally bitwise* equal to a
 cold ``plan_from_graph`` over the compacted edge arrays — CSR, ``u_cols``,
 ``remaining``, ``out_block``, ``first``, chunk width, and slot maps — and
@@ -224,7 +224,7 @@ class _LayoutState:
 
 @dataclasses.dataclass
 class FlushResult:
-    """What one flush did — surfaced to telemetry and the mutation bench."""
+    """What one flush did — surfaced to telemetry and the graph stream."""
 
     epoch: int
     inserted: int
@@ -380,9 +380,8 @@ class DeltaGraphState:
     def repack(self) -> Tuple[DedupChunks, DedupChunks]:
         """Host-side incremental re-pack at the current epoch: the forward
         and transpose DedupChunks layouts, assembled from cached clean
-        blocks + the re-chunked dirty ones.  This is the delta side of the
-        ``delta_repack_speedup`` bench comparison (device upload is
-        identical either way and excluded from both)."""
+        blocks + the re-chunked dirty ones; ``cold_repack`` is the
+        reference it must equal."""
         return (self._fwd.assemble(self._w, self.width_multiple),
                 self._tr.assemble(self._w, self.width_multiple))
 
@@ -459,7 +458,7 @@ class DeltaGraphState:
                                                       "pallas"), **kwargs):
         """The cold re-pack reference: ``plan_from_graph`` over the
         compacted canonical arrays (what the incremental plan must match
-        at every epoch boundary) — also the mutation bench's baseline."""
+        at every epoch boundary)."""
         from repro.sparse.plan import plan_from_graph
         return plan_from_graph(self.graph(), backends=backends, **kwargs)
 

@@ -23,8 +23,8 @@ nevertheless defensive — ``observe`` silently drops anything that will not
 importing jax here.  The module is dependency-free (stdlib only) so any
 layer — ``repro.core`` included — can reach it without an import cycle.
 
-``stats()`` is the one-call export benches and ``neurascope`` consume:
-the counter/series snapshot plus the plan-cache mirror.
+``stats()`` is the one-call export the live dashboard consumes: the
+counter/series snapshot plus the plan-cache mirror.
 """
 from __future__ import annotations
 

@@ -15,18 +15,30 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from _tolerances import Q8_E2E_TOL
 
+from repro.data.synthetic import powerlaw_graph
 from repro.sparse import backend as sb
 from repro.sparse.plan import (ALL_BACKENDS, BackendPlanError, edge_plan,
                                make_plan)
 
 PARITY_BACKENDS = ("chunked", "pallas")
+# (n, e, d, chunk, powerlaw): a small uniform graph, and a power-law one
+# with hub senders (hub rows of the transposed, backward layout) that the
+# chunked executor evicts in two waves
+GRAPHS = {"uniform": (64, 400, 24, 128, False),
+          "powerlaw": (1024, 4096, 32, 2048, True)}
 
 
-def _random_plan_inputs(n, e, seed, weighted=True, n_invalid=0):
+def _random_plan_inputs(n, e, seed, weighted=True, n_invalid=0,
+                        powerlaw=False):
     rng = np.random.default_rng(seed)
-    s = rng.integers(0, n, e)
-    r = rng.integers(0, n, e)
+    if powerlaw:   # hub senders; a few self loops are dropped, so oversample
+        s, r = powerlaw_graph(n, e + 256, seed=seed)
+        s, r = s[:e], r[:e]
+    else:
+        s = rng.integers(0, n, e)
+        r = rng.integers(0, n, e)
     w = rng.normal(size=e).astype(np.float32) if weighted else None
     valid = np.ones(e, bool)
     if n_invalid:
@@ -38,14 +50,16 @@ def _random_plan_inputs(n, e, seed, weighted=True, n_invalid=0):
 # Raw aggregate parity
 # ---------------------------------------------------------------------------
 
+@pytest.mark.parametrize("graph", GRAPHS)
 @pytest.mark.parametrize("backend", PARITY_BACKENDS)
 @pytest.mark.parametrize("weighted", [True, False])
-def test_aggregate_parity(backend, weighted):
-    n, e, d = 64, 400, 24
-    s, r, w, valid, rng = _random_plan_inputs(n, e, 0, weighted, n_invalid=60)
+def test_aggregate_parity(backend, weighted, graph):
+    n, e, d, chunk, powerlaw = GRAPHS[graph]
+    s, r, w, valid, rng = _random_plan_inputs(n, e, 0, weighted, n_invalid=60,
+                                              powerlaw=powerlaw)
     x = jnp.asarray(rng.normal(size=(n, d)).astype(np.float32))
     plan = make_plan(s, r, n, edge_weight=w, edge_valid=valid,
-                     backends=("dense", "chunked", "pallas"), chunk=128)
+                     backends=("dense", "chunked", "pallas"), chunk=chunk)
     ref = sb.aggregate(plan, None, x, backend="dense")
     out = sb.aggregate(plan, None, x, backend=backend)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -128,20 +142,27 @@ def _graph_and_plan(n, e, seed, weighted, n_invalid=0):
     if n_invalid:
         valid[e_tot - n_invalid:] = False
     plan = make_plan(s, r, n + 1, edge_weight=w, edge_valid=valid,
-                     backends=("dense", "chunked", "pallas"), chunk=128)
+                     backends=("dense", "chunked", "pallas", "pallas_q8"),
+                     chunk=128)
     return rng, plan
 
 
-@pytest.mark.parametrize("backend", PARITY_BACKENDS)
+@pytest.mark.parametrize("backend", PARITY_BACKENDS + ("pallas_q8",))
 @pytest.mark.parametrize("n_invalid", [0, 37])
 def test_gcn_forward_backend_parity(backend, n_invalid):
     from repro.models.gnn import gcn
+    from repro.sparse import quantize
     cfg = gcn.GCNConfig(d_in=12, d_hidden=8, n_classes=5, n_layers=2)
     rng, plan = _graph_and_plan(50, 200, 0, True, n_invalid)
     x = jnp.asarray(rng.normal(size=(51, cfg.d_in)).astype(np.float32))
     params = gcn.init_params(jax.random.key(0), cfg)
     ref = gcn.forward(params, cfg, x, backend="dense", plan=plan)
     out = gcn.forward(params, cfg, x, backend=backend, plan=plan)
+    if backend == "pallas_q8":
+        # per-layer bounds do not compose through the model's nonlinearity
+        dev = float(jnp.abs(out - ref).max())
+        assert quantize.q8_gate(dev, Q8_E2E_TOL), dev
+        return
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-4, atol=1e-4)
 
@@ -322,17 +343,22 @@ def test_pallas_empty_blocks_evict_zeros():
                                rtol=1e-5, atol=1e-5)
 
 
-def test_pallas_vjp_matches_dense_autodiff():
-    """Custom-VJP cotangents for BOTH `vals` and `x` match dense autodiff;
-    the backward runs through the Pallas kernel, not a segment reduction."""
+@pytest.mark.parametrize("graph", GRAPHS)
+@pytest.mark.parametrize("backend", PARITY_BACKENDS + ("distributed",))
+def test_pallas_vjp_matches_dense_autodiff(backend, graph):
+    """Cotangents for BOTH `vals` and `x` (the training path) match dense
+    autodiff on every executor; the `distributed` one runs over however
+    many devices the process has.  On `pallas` the custom-VJP backward runs
+    through the Pallas kernel, not a segment reduction."""
     import inspect
     from repro.kernels.gustavson_spmm import ops as gops
-    n, e, d = 40, 250, 12
-    s, r, w, valid, rng = _random_plan_inputs(n, e, 9, n_invalid=30)
+    n, e, d, chunk, powerlaw = GRAPHS[graph]
+    s, r, w, valid, rng = _random_plan_inputs(n, e, 9, n_invalid=30,
+                                              powerlaw=powerlaw)
     x = jnp.asarray(rng.normal(size=(n, d)).astype(np.float32))
     vals = jnp.asarray(w)
-    plan = make_plan(s, r, n, edge_valid=valid,
-                     backends=("dense", "chunked", "pallas"))
+    plan = make_plan(s, r, n, edge_valid=valid, chunk=chunk,
+                     backends=("dense", "chunked", "pallas", "distributed"))
 
     def loss(v, xx, nm):
         y = sb.aggregate(plan, v, xx, backend=nm)
@@ -340,13 +366,14 @@ def test_pallas_vjp_matches_dense_autodiff():
 
     gv_d, gx_d = jax.grad(loss, argnums=(0, 1))(vals, x, "dense")
     gv_p, gx_p = jax.jit(jax.grad(loss, argnums=(0, 1)),
-                         static_argnums=2)(vals, x, "pallas")
+                         static_argnums=2)(vals, x, backend)
     np.testing.assert_allclose(np.asarray(gv_p), np.asarray(gv_d),
                                rtol=1e-4, atol=1e-5)
     np.testing.assert_allclose(np.asarray(gx_p), np.asarray(gx_d),
                                rtol=1e-4, atol=1e-5)
-    # the acceptance contract: no plain-JAX segment reduction in the bwd
-    assert "segment_sum" not in inspect.getsource(gops._ad_bwd)
+    if backend == "pallas":
+        # the acceptance contract: no plain-JAX segment reduction in the bwd
+        assert "segment_sum" not in inspect.getsource(gops._ad_bwd)
 
 
 def test_pallas_bf16_stays_bf16():

@@ -165,12 +165,25 @@ def test_distributed_backend_has_no_delta_path():
 
 
 def test_incremental_beats_cold_on_sparse_deltas():
-    """Sanity (not the perf gate — cluster_bench owns that): a small delta
-    on a big graph re-chunks only the dirty blocks."""
+    """Small deltas on a big graph re-chunk only the dirty blocks, and over
+    several epochs of inserts and deletes the incremental layouts stay
+    bitwise equal to a cold pack."""
     rng = np.random.default_rng(0)
     n, e = 4096, 60_000
     d = DeltaGraphState(rng.integers(0, n, e), rng.integers(0, n, e), n)
-    for _ in range(32):
-        d.insert_edge(int(rng.integers(0, n)), int(rng.integers(0, n)))
-    res = d.flush()
-    assert res.dirty_blocks < res.clean_blocks
+    for _ in range(3):
+        for _ in range(32):
+            d.insert_edge(int(rng.integers(0, n)), int(rng.integers(0, n)))
+        for _ in range(10):
+            k = int(rng.integers(0, d._s.size))
+            try:
+                d.delete_edge(int(d._s[k]), int(d._r[k]))
+            except DeltaGraphError:
+                pass               # every copy of that edge already booked
+        res = d.flush()
+        assert res.dirty_blocks < res.clean_blocks
+        for inc, cold in zip(d.repack(), d.cold_repack()):
+            ok, detail = chunks_match(inc, cold)
+            assert ok, detail
+    ok, detail = plans_match(d.plan(), d.cold_plan())
+    assert ok, detail

@@ -236,19 +236,26 @@ def test_padding_lanes_do_not_leak():
 # engine end-to-end
 # ---------------------------------------------------------------------------
 
-def _server(backend="dense", **kw):
-    from repro.models.gnn import gcn
+CONFIGS = {"gcn": "GCNConfig", "sage": "SAGEConfig", "gin": "GINConfig"}
+
+
+def _server(backend="dense", arch="gcn", **kw):
+    mod = __import__(f"repro.models.gnn.{arch}", fromlist=[arch])
     indptr, indices = _csr()
-    cfg = gcn.GCNConfig(d_in=D, d_hidden=8, n_classes=5)
-    params = gcn.init_params(jax.random.key(0), cfg)
-    return GNNServer("gcn", cfg, params, indptr, indices, _store(),
+    cfg = getattr(mod, CONFIGS[arch])(d_in=D, d_hidden=8, n_classes=5)
+    params = mod.init_params(jax.random.key(0), cfg)
+    return GNNServer(arch, cfg, params, indptr, indices, _store(),
                      fanouts=FANOUTS, backend=backend, max_batch_seeds=8,
                      max_wait_ms=2.0, n_workers=2, seed=0, **kw)
 
 
-def test_engine_serves_all_exactly_once_with_parity():
+@pytest.mark.parametrize("arch,backend", [("gcn", "dense"),
+                                          ("gcn", "pallas"),
+                                          ("sage", "dense"),
+                                          ("gin", "dense")])
+def test_engine_serves_all_exactly_once_with_parity(arch, backend):
     rng = np.random.default_rng(3)
-    with _server() as server:
+    with _server(backend, arch) as server:
         server.warmup()
         reqs = [server.submit(rng.integers(0, N, size=rng.integers(1, 4)))
                 for _ in range(17)]

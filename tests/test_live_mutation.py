@@ -62,11 +62,16 @@ def _submit_load(srv, rng, n=24):
 # Hot swap
 # ---------------------------------------------------------------------------
 
-def test_three_swaps_under_load_exactly_once(tmp_path):
+@pytest.mark.parametrize("mutate", [False, True])
+def test_three_swaps_under_load_exactly_once(tmp_path, mutate):
     """The drill core: 3 consecutive swaps with traffic in flight — every
-    request settles exactly once on exactly one version, nothing lost."""
-    srv, params, _, _ = _server()
+    request settles exactly once on exactly one version, nothing lost.
+    With ``mutate``, a streaming edge flush between cycles installs under
+    the same load, each proven equal to a cold re-pack."""
+    srv, params, indptr, indices = _server()
     rng = np.random.default_rng(0)
+    s0, r0 = _csr_to_coo(indptr, indices)
+    gs = GraphStream(srv, max_pending=256, parity_every=1)
     all_reqs = []
     try:
         for k in (1, 2, 3):
@@ -77,10 +82,20 @@ def test_three_swaps_under_load_exactly_once(tmp_path):
             rep = hot_swap(srv, tmp_path, step=k, drain_timeout=60.0)
             assert rep.version == k and rep.old_version == k - 1
             assert rep.drained_old, "old version never drained"
+            if mutate:
+                for _ in range(16):
+                    gs.insert(int(rng.integers(0, N_NODES)),
+                              int(rng.integers(0, N_NODES)))
+                for i in range(4 * k - 4, 4 * k):
+                    gs.delete(int(s0[i]), int(r0[i]))
+                assert gs.flush().parity_ok
             all_reqs += _submit_load(srv, rng)
         srv.drain()
     finally:
         srv.close()
+    if mutate:
+        assert len(gs.flushes) == 3
+        assert {r.graph_epoch for r in all_reqs[-24:]} == {3}
     assert len(all_reqs) == 6 * 24
     for r in all_reqs:
         assert r.n_settles == 1, f"rid {r.rid} settled {r.n_settles}×"

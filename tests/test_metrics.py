@@ -1,16 +1,18 @@
 """Streaming metrics plane + per-class SLO burn-rate engine (DESIGN.md §15).
 
 Histogram/exposition/SLO logic is pure host code on virtual clocks — no
-jax, no wall-clock flake.  The single jax-backed test at the bottom proves
-the full wiring: a live ``ClusterServer`` under unreachable latency
-targets must shed best_effort before any interactive request, and its
-scraped ``/metrics`` exposition must agree with the engine's own summary.
+jax, no wall-clock flake.  The jax-backed tests at the bottom prove the
+full wiring: a live ``ClusterServer`` under unreachable latency targets
+must shed best_effort before any interactive request, and its scraped
+``/metrics`` exposition must agree with the engine's own summary; a
+metered ``GNNServer`` must export every required family.
 
 Property tests run under real ``hypothesis`` when installed, else the
 deterministic shim (``tests/_hypothesis_shim.py``).
 """
 import math
 import threading
+import time
 import urllib.request
 
 import pytest
@@ -445,10 +447,12 @@ def test_cluster_slo_sheds_best_effort_before_interactive():
                 r.wait_done(timeout=60)
             if shed["best_effort"] >= 3 and int_after_shed >= 3:
                 break
+        time.sleep(0.06)         # one hub tick past the last observation
         st_classes = srv.stats()["classes"]
         with urllib.request.urlopen(srv.stats()["metrics_url"],
                                     timeout=10) as resp:
             fams = parse_exposition(resp.read().decode())
+        summary = srv.slo.summary()
         events = [e for e in srv.telemetry.events
                   if e.get("event") == "shed_class" and e.get("on")]
     assert shed["best_effort"] >= 3 and shed["interactive"] == 0
@@ -463,3 +467,40 @@ def test_cluster_slo_sheds_best_effort_before_interactive():
         counts = histogram_counts_from_samples(hist, {"class": cls})
         scraped = quantile_from_counts(counts, 0.99)
         assert abs(scraped - bucket_index(s["p99_ms"] / 1e3)) <= 1
+    # the exported fast-window burn gauge tracks the engine's own summary
+    burn = {labels.get("class"): v for _n, labels, v, _ex in
+            fams["neurachip_slo_burn_rate"]["samples"]
+            if labels.get("window") == "fast"}
+    for cls, s in summary.items():
+        if s["n"]:
+            ref = max(abs(s["burn_fast"]), 1.0)
+            assert abs(burn.get(cls, 0.0) - s["burn_fast"]) / ref <= 0.25
+
+
+# ---------------------------------------------------------------------------
+# The single-lane engine's exposition endpoint
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("sampler", ["host", "device"])
+def test_engine_metrics_endpoint_exports_required_families(sampler):
+    """A metered ``GNNServer`` serves a parseable exposition, scraped while
+    it is up, that holds every family an operator's dashboard reads."""
+    from repro.launch.gnn_serve import build_world
+    from repro.serve import GNNServer
+
+    cfg, params, indptr, indices, store = build_world("gcn", 256, 1024, 8,
+                                                      seed=0)
+    with GNNServer("gcn", cfg, params, indptr, indices, store,
+                   fanouts=(2, 2), backend="dense", sampler=sampler,
+                   max_batch_seeds=4, max_wait_ms=1.0, seed=0,
+                   metrics_port=0) as srv:
+        for r in [srv.submit([s]) for s in range(0, 256, 16)]:
+            r.wait(120)
+        with urllib.request.urlopen(srv.stats()["metrics_url"],
+                                    timeout=10) as resp:
+            assert resp.status == 200
+            fams = parse_exposition(resp.read().decode())
+    for fam in ("neurachip_requests_total",
+                "neurachip_request_latency_seconds", "neurachip_queue",
+                "neurachip_cache_hit_rate"):
+        assert fams.get(fam, {}).get("samples"), fam

@@ -11,13 +11,13 @@ try:
 except ImportError:  # deterministic fallback; requirements-dev.txt has the real one
     from _hypothesis_shim import given, settings, st
 
-from benchmarks.backend_sweep import aggregate_q8_bound_for
 from repro.data.synthetic import powerlaw_graph
 from repro.kernels.gustavson_spmm.gustavson_spmm import _auto_d_tile
 from repro.optim import compression
 from repro.sparse import backend as sparse_backend
 from repro.sparse import quantize
 from repro.sparse.plan import make_plan
+from repro.sparse.quantize import aggregate_q8_bound_for
 from repro.sparse.spgemm import make_spgemm_plan
 
 
@@ -156,6 +156,10 @@ def test_aggregate_q8_fwdbwd_runs_and_is_finite():
         assert bool(jnp.isfinite(got).all())
         # straight-through backward: close to the f32 gradient, not exact
         assert float(jnp.abs(ref - got).max()) < 0.2
+        # only the cotangent carries quantization error: the forward's
+        # scale-derived bound holds for the gradients too
+        assert quantize.q8_gate(float(jnp.abs(ref - got).max()),
+                                aggregate_q8_bound_for(plan, x))
 
 
 # ---------------------------------------------------------------------------

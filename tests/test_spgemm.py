@@ -143,15 +143,20 @@ def test_symbolic_matches_dense_on_powerlaw():
     np.testing.assert_array_equal(sym.row_nnz, c.sum(1))
 
 
-def test_symbolic_pp_matches_neurasim_walk():
-    """Engine-measured stats == the independent NeuraSim Eq.-1 walk."""
+@pytest.mark.parametrize("n,e", [(256, 1024), (512, 2048), (1024, 4096),
+                                 (2048, 8192)])
+def test_symbolic_pp_matches_neurasim_walk(n, e):
+    """Engine-measured stats == the independent NeuraSim Eq.-1 walk, on
+    the plan the executors run as well as on the bare symbolic phase."""
     from repro.data.synthetic import powerlaw_graph
     from repro.neurasim.model import stats_from_coo
-    s, r = powerlaw_graph(256, 1024, seed=4)
-    sym = symbolic(s, r, 256, s, r, 256)
-    w = stats_from_coo(s.astype(np.int64), r.astype(np.int64), 256)
+    s, r = powerlaw_graph(n, e, seed=4)
+    sym = symbolic(s, r, n, s, r, n)
+    w = stats_from_coo(s.astype(np.int64), r.astype(np.int64), n)
     assert sym.pp_interim == w.pp_interim
     assert sym.nnz_out == w.nnz_out
+    plan = make_spgemm_plan(s, r, n, s, r, n, chunk=4096)
+    assert (plan.pp_interim, plan.nnz_out) == (w.pp_interim, w.nnz_out)
 
 
 @given(st.integers(0, 2**31 - 1), st.sampled_from([64, 128, 256]))
@@ -217,8 +222,10 @@ def _full_parity(plan, c_vals, dense_c, tol=1e-4):
     np.testing.assert_allclose(got, dense_c, rtol=tol, atol=tol)
 
 
-@pytest.mark.parametrize("backend", ["reference", "pallas"])
-def test_spgemm_executor_parity_powerlaw(backend):
+@pytest.mark.parametrize("backend,values", [
+    pytest.param(be, v, id=be if v == "baked" else f"{be}-{v}")
+    for v in ("baked", "traced") for be in ("reference", "pallas")])
+def test_spgemm_executor_parity_powerlaw(backend, values):
     from repro.data.synthetic import powerlaw_graph
     rng = np.random.default_rng(0)
     n = 200
@@ -227,7 +234,11 @@ def test_spgemm_executor_parity_powerlaw(backend):
     bv = rng.normal(size=s.size).astype(np.float32)
     plan = make_spgemm_plan(r, s, n, r, s, n, a_vals=av, b_vals=bv,
                             chunk=256)
-    c = sb.spgemm(plan, backend=backend)
+    if values == "baked":
+        c = sb.spgemm(plan, backend=backend)
+    else:
+        c = jax.jit(lambda a, b: sb.spgemm(plan, a, b, backend=backend))(
+            jnp.asarray(av), jnp.asarray(bv))
     dense_c = _dense_of(r, s, av, n, n) @ _dense_of(r, s, bv, n, n)
     _full_parity(plan, c, dense_c)
     assert plan.pp_dedup <= plan.pp_interim     # operand reuse never inflates

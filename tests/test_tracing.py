@@ -5,8 +5,8 @@ retried, re-routed across a lane kill, deadline-expired, or force-failed at
 close — yields **exactly one** complete span tree with **exactly one**
 terminal span (``settle`` XOR ``error``), and tracing disabled allocates
 nothing at all.  ``tracing.verify_trace``/``verify_traces`` is the single
-verifier shared with ``neurascope --check``, so a CI smoke failure and a
-test failure here always agree on what "well-formed" means.
+verifier shared with ``neurascope --check`` (``tests/test_neurascope.py``),
+so the two always agree on what "well-formed" means.
 """
 import gc
 import json
